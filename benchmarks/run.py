@@ -25,6 +25,8 @@ def main(argv=None) -> None:
                          "roofline); default: all")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
     steps = int(os.environ.get("REPRO_BENCH_STEPS", "150"))
     rows = []
 

@@ -91,7 +91,8 @@ class ModelConfig:
     dtype: str = "bfloat16"
     remat: str = "full"          # "none" | "full" | "dots"
     logits_softcap: float = 0.0
-    use_pallas: str = "auto"     # "auto" | "never" | "always"
+    use_pallas: str = "auto"     # "auto" (on TPU) | "never" | "always": the
+                                 # one kernel switch (kernels/ops.use_kernels)
     seq_shard_kv: bool = False   # constrain k/v activations S-sharded over
                                  # "model" (stops GSPMD split-contraction
                                  # all-reduces; see EXPERIMENTS.md Perf)

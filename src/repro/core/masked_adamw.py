@@ -32,6 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import OptimizerConfig
 from repro.core import partition as part_mod
@@ -63,14 +64,24 @@ def clip_by_global_norm(grads, max_norm: float):
 
 
 def _adamw_rows(cfg: OptimizerConfig, p, g, m, v, sel, cnt, lr,
-                pallas_ok: bool):
+                use_pallas: bool, mesh=None, spec=P()):
     """The masked-AdamW formula on one leaf (or gathered bank rows of one
-    leaf). ``sel``/``cnt`` broadcast against ``p``. Shared by the dense and
-    banked layouts so their arithmetic is identical op for op."""
-    if pallas_ok:
+    leaf). ``sel``/``cnt`` broadcast against ``p``: per-row [L, 1, ...] for
+    a stacked leaf, scalars for an unstacked one. Shared by the dense and
+    banked layouts so their arithmetic is identical op for op; with
+    ``use_pallas`` the fused kernel applies it, an unstacked leaf running as
+    a stack of one, per shard of ``spec`` on a multi-device ``mesh``."""
+    if use_pallas:
         from repro.kernels import ops as kops
-        return kops.masked_adamw(p, g, m, v, sel, cnt, lr, cfg.b1, cfg.b2,
-                                 cfg.eps, cfg.weight_decay)
+        hp = (cfg.b1, cfg.b2, cfg.eps, cfg.weight_decay)
+        if sel.ndim:
+            return kops.masked_adamw(p, g, m, v, sel, cnt, lr, *hp,
+                                     mesh=mesh, spec=spec)
+        one = lambda x: jnp.asarray(x)[None]  # noqa: E731
+        out = kops.masked_adamw(one(p), one(g), one(m), one(v), one(sel),
+                                one(cnt), lr, *hp, mesh=mesh,
+                                spec=P(None, *spec))
+        return tuple(x[0] for x in out)
     mdt = m.dtype
     gf = g.astype(jnp.float32)
     m, v = m.astype(jnp.float32), v.astype(jnp.float32)
@@ -92,21 +103,24 @@ def _unzip3(flat):
 
 
 def update(cfg: OptimizerConfig, partition: BlockPartition, params: dict,
-           grads: dict, opt_state: dict, mask, lr, use_pallas: bool = False):
+           grads: dict, opt_state: dict, mask, lr, use_pallas: bool = False,
+           mesh=None, specs=None):
     """One masked step. mask: [num_blocks]; lr: scalar (schedule applied by
-    the caller). Returns (new_params, new_opt_state)."""
+    the caller). ``mesh``/``specs``: where the kernels run per shard — the
+    multi-device mesh and the moments' PartitionSpecs (congruent with
+    params; None = replicated). Returns (new_params, new_opt_state)."""
     counts = opt_state["counts"] + mask.astype(jnp.float32)
     masks = leaf_masks(partition, params, mask)
     counts_b = leaf_masks(partition, params, counts)  # per-leaf broadcast
+    if specs is None:
+        specs = jax.tree.map(lambda _: P(), params)
 
-    def upd(p, g, m, v, sel, cnt):
-        # Pallas needs a per-row [L, 1, ...] mask — unstacked leaves get a
-        # scalar from leaf_masks, so they take the jnp path.
-        pallas_ok = use_pallas and p.ndim >= 2 and sel.ndim == p.ndim
-        return _adamw_rows(cfg, p, g, m, v, sel, cnt, lr, pallas_ok)
+    def upd(p, g, m, v, sel, cnt, spec):
+        return _adamw_rows(cfg, p, g, m, v, sel, cnt, lr, use_pallas, mesh,
+                           spec)
 
     flat = jax.tree.map(upd, params, grads, opt_state["m"], opt_state["v"],
-                        masks, counts_b)
+                        masks, counts_b, specs)
     new_params, new_m, new_v = _unzip3(flat)
     return new_params, {"m": new_m, "v": new_v, "counts": counts}
 
@@ -416,7 +430,7 @@ def swap_banked(partition: BlockPartition, banks: dict, store: dict,
 
 def banked_update(cfg: OptimizerConfig, partition: BlockPartition,
                   params: dict, grads: dict, banks: dict, counts, mask, lr,
-                  use_pallas: bool = False):
+                  use_pallas: bool = False, mesh=None, specs=None):
     """One masked AdamW step on the compact banks (jit-safe; every index is
     a runtime vector of static shape). Assumes residency == selection —
     ``swap_banked`` ran at the last selection change, so every masked
@@ -425,8 +439,12 @@ def banked_update(cfg: OptimizerConfig, partition: BlockPartition,
     (grads, mask, lr) sequence the two layouts are trajectory-exact, which
     keeps the dense implementation as the oracle. Non-resident blocks'
     params (and their store moments) are untouched bit for bit.
+    ``mesh``/``specs``: where the kernels run per shard — the multi-device
+    mesh and the params' PartitionSpecs (None = replicated).
     Returns (new_params, new_banks, new_counts)."""
     mask = jnp.asarray(mask)
+    if specs is None:
+        specs = jax.tree.map(lambda _: P(), params)
     counts = jnp.asarray(counts) + mask.astype(jnp.float32)
     new_params, new_banks = {}, {}
     for g in partition.groups:
@@ -438,15 +456,15 @@ def banked_update(cfg: OptimizerConfig, partition: BlockPartition,
             sel = jnp.where(valid, mask[gids].astype(jnp.float32), 0.0)
             cnt = counts[gids]
 
-            def upd(p, gr, m, v):
-                if use_pallas and p.ndim >= 2:
+            def upd(p, gr, m, v, spec):
+                if use_pallas:
                     # fused path: the kernel fetches p/g rows through the
                     # slots vector (scalar prefetch) — no [cap, ...] gather
                     # of p or g is materialized, only the compact outputs.
                     from repro.kernels import ops as kops
                     p2, m2, v2 = kops.banked_masked_adamw(
                         p, gr, m, v, slots, sel, cnt, lr, cfg.b1, cfg.b2,
-                        cfg.eps, cfg.weight_decay)
+                        cfg.eps, cfg.weight_decay, mesh=mesh, spec=spec)
                     return part_mod.scatter_rows(p, slots, p2), m2, v2
                 p_rows = part_mod.gather_rows(p, slots)
                 g_rows = part_mod.gather_rows(gr, slots)
@@ -458,19 +476,19 @@ def banked_update(cfg: OptimizerConfig, partition: BlockPartition,
                 return part_mod.scatter_rows(p, slots, p2), m2, v2
 
             flat = jax.tree.map(upd, params[g.key], grads[g.key],
-                                bank["m"], bank["v"])
+                                bank["m"], bank["v"], specs[g.key])
         else:
             resident = slots[0] < g.length
             sel = jnp.where(resident, mask[g.start].astype(jnp.float32), 0.0)
             cnt = counts[g.start]
 
-            def upd(p, gr, m, v):
-                # scalar sel/cnt broadcast; no Pallas (kernel wants per-row
-                # vectors — same rule as the dense path's unstacked leaves)
-                return _adamw_rows(cfg, p, gr, m, v, sel, cnt, lr, False)
+            def upd(p, gr, m, v, spec):
+                # scalar sel/cnt broadcast, as for the dense unstacked leaves
+                return _adamw_rows(cfg, p, gr, m, v, sel, cnt, lr,
+                                   use_pallas, mesh, spec)
 
             flat = jax.tree.map(upd, params[g.key], grads[g.key],
-                                bank["m"], bank["v"])
+                                bank["m"], bank["v"], specs[g.key])
         p_new, m_new, v_new = _unzip3(flat)
         new_params[g.key] = p_new
         new_banks[g.key] = {"m": m_new, "v": v_new, "slots": slots}

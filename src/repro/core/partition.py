@@ -16,6 +16,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 
@@ -85,28 +86,40 @@ def build_partition(cfg: ModelConfig) -> BlockPartition:
 
 
 def block_grad_norms(partition: BlockPartition, grads: dict,
-                     use_pallas: bool = False) -> jax.Array:
+                     use_pallas: bool = False, mesh=None,
+                     specs=None) -> jax.Array:
     """Per-block gradient L2 norm (paper Alg. 1 lines 1-6): aggregates
-    sum-of-squares over every leaf of each block, sqrt at the end.
+    sum-of-squares over every leaf of each block, sqrt at the end. With
+    ``use_pallas`` the fused kernel reduces every leaf, an unstacked leaf
+    as a stack of one — per shard of the grads' PartitionSpecs ``specs``
+    (congruent with grads; None = replicated) on a multi-device ``mesh``.
     Returns [num_blocks] f32."""
+    if specs is None:
+        specs = jax.tree.map(lambda _: P(), grads)
     if use_pallas:
         from repro.kernels import ops as kops
-        stacked_sq = kops.block_grad_sq_norms
+
+        def stacked_sq(leaf, spec):
+            return kops.block_grad_sq_norms(leaf, mesh=mesh, spec=spec)
     else:
-        stacked_sq = None
+        def stacked_sq(leaf, spec):
+            lf = leaf.astype(jnp.float32)
+            return jnp.sum(lf * lf, axis=tuple(range(1, lf.ndim)))
     sq = jnp.zeros((partition.num_blocks,), jnp.float32)
     for g in partition.groups:
         sub = grads[g.key]
         leaves = jax.tree.leaves(sub)
+        leaf_specs = jax.tree.leaves(specs[g.key],
+                                     is_leaf=lambda s: isinstance(s, P))
         if g.stacked:
             acc = jnp.zeros((g.length,), jnp.float32)
-            for leaf in leaves:
-                if stacked_sq is not None and leaf.ndim >= 2:
-                    acc = acc + stacked_sq(leaf)
-                else:
-                    lf = leaf.astype(jnp.float32)
-                    acc = acc + jnp.sum(lf * lf, axis=tuple(range(1, lf.ndim)))
+            for leaf, spec in zip(leaves, leaf_specs):
+                acc = acc + stacked_sq(leaf, spec)
             sq = jax.lax.dynamic_update_slice(sq, acc, (g.start,))
+        elif use_pallas:
+            s = sum(stacked_sq(x[None], P(None, *spec))[0]
+                    for x, spec in zip(leaves, leaf_specs))
+            sq = sq.at[g.start].add(s)
         else:
             s = sum(jnp.sum(jnp.square(x.astype(jnp.float32))) for x in leaves)
             sq = sq.at[g.start].add(s)

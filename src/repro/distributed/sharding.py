@@ -22,22 +22,6 @@ from repro.configs.base import ModelConfig
 from repro.utils.trees import tree_map_with_path
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax lines: the >=0.6 line takes
-    ``check_vma``, older lines spell it ``check_rep`` (and pre-promotion
-    only ship ``jax.experimental.shard_map``). Every in-repo shard_map goes
-    through here so the repo runs on both."""
-    import inspect
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:  # pragma: no cover - older jax line
-        from jax.experimental.shard_map import shard_map as sm
-    kw = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-          else "check_rep")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check})
-
-
 def _model_dim(mesh) -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
 

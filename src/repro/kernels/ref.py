@@ -67,20 +67,24 @@ def decode_attention(q, k, v, valid_len):
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_tables, valid_len, hmap):
-    """q: [B, H, D]; k_pool/v_pool: [num_pages, page_size, KVH, D];
+    """q: [B, H, D]; k_pool/v_pool: [num_pages, KVH, page_size, D];
     page_tables: [B, max_pages] i32 (entries >= num_pages are unallocated
     sentinels: clamped for the gather, masked by valid_len); hmap: [H] i32
     q-head -> kv-head map. Gathers the pool into the dense per-row view and
     defers to the dense oracle."""
     b = q.shape[0]
-    num_pages, ps, kvh, d = k_pool.shape
+    num_pages, kvh, ps, d = k_pool.shape
     tbl = jnp.minimum(jnp.asarray(page_tables, jnp.int32), num_pages - 1)
     maxp = tbl.shape[1]
-    dense = lambda pool: pool[tbl].reshape(b, maxp * ps, kvh, d)  # noqa: E731
     hm = jnp.asarray(hmap)
-    kd = dense(k_pool)[:, :, hm, :].transpose(0, 2, 1, 3)  # [B, H, S, D]
-    vd = dense(v_pool)[:, :, hm, :].transpose(0, 2, 1, 3)
-    return decode_attention(q, kd, vd, jnp.asarray(valid_len))
+
+    def dense(pool):  # [B, maxp, KVH, ps, D] -> [B, H, maxp * ps, D]
+        rows = pool[tbl].transpose(0, 2, 1, 3, 4).reshape(b, kvh,
+                                                          maxp * ps, d)
+        return rows[:, hm]
+
+    return decode_attention(q, dense(k_pool), dense(v_pool),
+                            jnp.asarray(valid_len))
 
 
 def rmsnorm(x, scale, eps=1e-5):

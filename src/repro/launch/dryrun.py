@@ -164,8 +164,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
                                              train_method=train_method)
         ma = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # jax<0.6 returns [dict]
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         if hlo_dir:
             import gzip

@@ -7,19 +7,11 @@ state (the dry-run must set XLA_FLAGS before any device query).
 and raise an actionable error (requested shape, found devices, and the
 XLA_FLAGS incantation for CPU testing) instead of jax's opaque
 "len(devices) != prod(shape)" failure deep inside mesh_utils.
-
-Compat: ``jax.sharding.AxisType`` only exists on the jax>=0.6 line; on older
-jax the mesh is built without explicit axis types (everything is Auto there
-anyway).
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax line
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.configs.base import MeshConfig, MULTI_POD, SINGLE_POD, TINY_MESH
 
@@ -39,10 +31,7 @@ def _check_device_count(shape: tuple, axes: tuple) -> None:
 
 def _make(shape: tuple, axes: tuple):
     _check_device_count(shape, axes)
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -71,7 +71,9 @@ def main():
     args = ap.parse_args()
 
     from repro import obs
+    from repro.launch.compile_cache import place_compile_cache
 
+    place_compile_cache()
     obs_on = bool(args.trace or args.metrics_json)
     if obs_on:
         obs.enable(selection=False)

@@ -24,7 +24,7 @@ import json
 import numpy as np
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     from repro import methods
 
     ap = argparse.ArgumentParser()
@@ -106,14 +106,11 @@ def main():
     ap.add_argument("--report", action="store_true",
                     help="print the selection-frequency heatmap "
                          "(exploration->exploitation view) after training")
-    args = ap.parse_args()
+    return ap
 
-    from repro import obs
 
-    obs_on = bool(args.trace or args.metrics_json or args.report)
-    if obs_on:
-        obs.enable()
-
+def train_config(args: argparse.Namespace):
+    """The ``TrainConfig`` the command line describes."""
     from repro.configs import get_config, get_smoke_config
     from repro.configs.base import OptimizerConfig, SelectConfig, TrainConfig
 
@@ -134,7 +131,13 @@ def main():
         steps=args.steps, seed=args.seed,
         checkpoint_dir=args.checkpoint_dir,
         checkpoint_every=args.checkpoint_every)
+    return tcfg
 
+
+def build_trainer(args: argparse.Namespace):
+    """The ``Trainer`` that ``main`` runs for parsed ``args`` (config, mesh
+    and data source as the command line says)."""
+    tcfg = train_config(args)
     mesh = None
     batch_axes = ("data",)
     if args.mesh:
@@ -163,9 +166,24 @@ def main():
             seed=args.seed, path=args.data_path, pack=args.pack)
 
     from repro.train.trainer import Trainer
-    trainer = Trainer(tcfg, mesh=mesh, batch_axes=batch_axes,
-                      data_source=data_source,
-                      prefetch_depth=args.prefetch_depth)
+    return Trainer(tcfg, mesh=mesh, batch_axes=batch_axes,
+                   data_source=data_source,
+                   prefetch_depth=args.prefetch_depth)
+
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+
+    from repro import obs
+    from repro.launch.compile_cache import place_compile_cache
+
+    place_compile_cache()
+    obs_on = bool(args.trace or args.metrics_json or args.report)
+    if obs_on:
+        obs.enable()
+
+    trainer = build_trainer(args)
+    mcfg = trainer.tcfg.model
     report = trainer.method.trainable_param_report(mcfg, trainer.state)
     resident = (f", resident {report.opt_bytes_resident / (1 << 20):.1f} MiB"
                 if report.opt_bytes_resident >= 0 else "")

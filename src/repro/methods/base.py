@@ -70,9 +70,12 @@ class FinetuneMethod(Protocol):
         ...
 
     def make_step(self, model_cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
-                  mesh=None, batch_axes=("data",), use_pallas: bool = False,
-                  donate: bool = True, state_shardings=None):
+                  mesh=None, batch_axes=("data",), donate: bool = True,
+                  state_shardings=None):
         """-> jitted ``(state, batch) -> (state, metrics)``.
+
+        Whether the step runs the Pallas kernels is the model's decision
+        (``kernels.ops.use_kernels(model_cfg)``), not the caller's.
 
         ``state_shardings`` (the method's ``state_shardings()`` tree, passed
         by the trainer when a mesh is active) lets the step pin its state

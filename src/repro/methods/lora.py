@@ -40,8 +40,8 @@ class LoRAMethod:
 
     # --------------------------------------------------------------- step
     def make_step(self, model_cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
-                  mesh=None, batch_axes=("data",), use_pallas: bool = False,
-                  donate: bool = True, state_shardings=None):
+                  mesh=None, batch_axes=("data",), donate: bool = True,
+                  state_shardings=None):
         model = model_registry.get(model_cfg)
         rank, alpha = opt_cfg.lora_rank, opt_cfg.lora_alpha
 

@@ -162,8 +162,8 @@ class SelectionMethod:
 
     # --------------------------------------------------------------- step
     def make_step(self, model_cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
-                  mesh=None, batch_axes=("data",), use_pallas: bool = False,
-                  donate: bool = True, state_shardings=None):
+                  mesh=None, batch_axes=("data",), donate: bool = True,
+                  state_shardings=None):
         """-> ``(state, batch) -> (state, metrics)``.
 
         Dense residency: one jitted function. Banked residency: a Python
@@ -179,7 +179,22 @@ class SelectionMethod:
         (GSPMD) program, gradients are mean-reduced over ``data`` *before*
         the in-jit selection — every device sees identical block norms and
         picks identical blocks by construction.
+
+        Block grad-norm and (banked) masked AdamW run as Pallas kernels
+        exactly when ``kernels.ops.use_kernels(model_cfg)`` says so — the
+        same switch that picks the paged decode kernel when serving. On a
+        mesh they run per shard, in the layout of the state they touch.
         """
+        from repro.kernels import ops as kops
+        use_pallas = kops.use_kernels(model_cfg)
+        kmesh = mesh if use_pallas else None
+        spec_of = lambda shardings: jax.tree.map(  # noqa: E731
+            lambda s: s.spec, shardings)
+        p_specs = m_specs = None
+        if kmesh is not None and state_shardings is not None:
+            p_specs = spec_of(state_shardings["params"])
+            if opt_cfg.moment_residency == "device":
+                m_specs = spec_of(state_shardings["opt"]["m"])
         sel_cfg = self.sel_cfg
         model = model_registry.get(model_cfg)
         partition = part_mod.build_partition(model_cfg)
@@ -210,8 +225,9 @@ class SelectionMethod:
 
             grads, gnorm = masked_adamw.clip_by_global_norm(
                 grads, opt_cfg.grad_clip)
-            block_norms = part_mod.block_grad_norms(partition, grads,
-                                                    use_pallas=use_pallas)
+            block_norms = part_mod.block_grad_norms(
+                partition, grads, use_pallas=use_pallas, mesh=kmesh,
+                specs=p_specs)
             if gate:
                 mask = pre_mask
                 # observe norms post-hoc (only computed blocks contribute)
@@ -232,7 +248,8 @@ class SelectionMethod:
             return self._make_banked_step(
                 opt_cfg, partition, forward_select, step_metrics,
                 use_pallas=use_pallas, donate=donate,
-                state_shardings=state_shardings, mesh=mesh)
+                state_shardings=state_shardings, mesh=mesh,
+                kernel_mesh=kmesh, p_specs=p_specs)
         if opt_cfg.moment_residency != "device":
             raise ValueError(
                 f"unknown moment_residency {opt_cfg.moment_residency!r}")
@@ -243,7 +260,7 @@ class SelectionMethod:
             lr = learning_rate(opt_cfg, state["step"])
             params, opt = masked_adamw.update(
                 opt_cfg, partition, state["params"], grads, state["opt"],
-                mask, lr, use_pallas=use_pallas)
+                mask, lr, use_pallas=use_pallas, mesh=kmesh, specs=m_specs)
             new_state = {"params": params, "opt": opt, "sel": sel_state,
                          "step": state["step"] + 1}
             new_state = _constrain(new_state, state_shardings)
@@ -254,7 +271,8 @@ class SelectionMethod:
 
     def _make_banked_step(self, opt_cfg, partition, forward_select,
                           step_metrics, *, use_pallas, donate,
-                          state_shardings=None, mesh=None):
+                          state_shardings=None, mesh=None, kernel_mesh=None,
+                          p_specs=None):
         shd = state_shardings
 
         def fwd_fn(params, sel_state, batch):
@@ -279,7 +297,7 @@ class SelectionMethod:
             lr = learning_rate(opt_cfg, step)
             params, banks, counts = masked_adamw.banked_update(
                 opt_cfg, partition, params, grads, banks, counts, mask, lr,
-                use_pallas=use_pallas)
+                use_pallas=use_pallas, mesh=kernel_mesh, specs=p_specs)
             if shd is not None:
                 params = _constrain(params, shd["params"])
                 banks = _constrain(banks, shd["opt"]["banks"])

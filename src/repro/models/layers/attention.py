@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models.layers import attention_core as core
 from repro.models.layers.rope import apply_rope
 
@@ -163,18 +164,10 @@ def apply_decode(params, cfg: ModelConfig, x, k_cache, v_cache, pos):
     return out, k_cache, v_cache
 
 
-def _use_paged_kernel(cfg: ModelConfig) -> bool:
-    if cfg.use_pallas == "always":
-        return True
-    if cfg.use_pallas == "never":
-        return False
-    return jax.default_backend() == "tpu"
-
-
 def apply_decode_paged(params, cfg: ModelConfig, x, k_pool, v_pool, pages,
                        pos):
     """One-token decode against a shared page pool. x: [B, 1, D]; pools
-    [num_pages, page_size, KVH, Dh] (one layer's slice); pages: [B,
+    [num_pages, KVH, page_size, Dh] (one layer's slice); pages: [B,
     max_pages] i32 per-slot page tables (entries >= num_pages unallocated);
     pos: per-row [B] write position. The new K/V scatters into pool page
     ``pages[b, pos // page_size]``; writes through sentinel entries (freed
@@ -182,7 +175,7 @@ def apply_decode_paged(params, cfg: ModelConfig, x, k_pool, v_pool, pages,
     keeps riding the decode chunk can never touch a reassigned page.
     Returns (out [B,1,D], new_k_pool, new_v_pool)."""
     b = x.shape[0]
-    num_pages, ps = k_pool.shape[0], k_pool.shape[1]
+    num_pages, ps = k_pool.shape[0], k_pool.shape[2]
     maxp = pages.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     positions = pos[:, None]
@@ -192,11 +185,10 @@ def apply_decode_paged(params, cfg: ModelConfig, x, k_pool, v_pool, pages,
                      pages[jnp.arange(b), jnp.minimum(pidx, maxp - 1)],
                      num_pages)
     off = pos % ps
-    k_pool = k_pool.at[page, off].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[page, off].set(v[:, 0].astype(v_pool.dtype))
+    k_pool = k_pool.at[page, :, off].set(k[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[page, :, off].set(v[:, 0].astype(v_pool.dtype))
     hmap = _hmap(cfg)
-    if _use_paged_kernel(cfg):
-        from repro.kernels import ops
+    if ops.use_kernels(cfg):
         out = ops.paged_decode_attention(q, k_pool, v_pool, pages, pos + 1,
                                          hmap)
     else:
@@ -205,9 +197,10 @@ def apply_decode_paged(params, cfg: ModelConfig, x, k_pool, v_pool, pages,
         # zeros) and reuse the dense decode attention, so paged and dense
         # engines are bit-identical on this path
         tbl = jnp.minimum(pages, num_pages - 1)
-        kvh, dh = k_pool.shape[2], k_pool.shape[3]
-        kd = k_pool[tbl].reshape(b, maxp * ps, kvh, dh)
-        vd = v_pool[tbl].reshape(b, maxp * ps, kvh, dh)
+        kvh, dh = k_pool.shape[1], k_pool.shape[3]
+        dense = lambda pool: pool[tbl].transpose(0, 1, 3, 2, 4).reshape(  # noqa: E731
+            b, maxp * ps, kvh, dh)
+        kd, vd = dense(k_pool), dense(v_pool)
         out = core.decode_attention(q, kd, vd, pos + 1, hmap=hmap,
                                     softcap=cfg.attn_logit_softcap)
     out = out.astype(x.dtype)

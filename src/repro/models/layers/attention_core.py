@@ -13,8 +13,10 @@ Execution paths:
     [B, H, chunk_q, S_k]).
   * ``decode_attention``   -- single-query attention against a length-masked
     KV cache.
-  * Pallas flash kernels (kernels/flash_attention.py) are dispatched by the
-    layer when cfg.use_pallas resolves to True on TPU.
+  * No layer calls the Pallas flash kernels (kernels/flash_attention.py):
+    they, the dense Pallas decode kernel and the RMSNorm kernel are reached
+    only from tests/test_kernels.py. The attention kernel on a main path is
+    the paged decode kernel, called by layers/attention.apply_decode_paged.
 """
 from __future__ import annotations
 

@@ -274,9 +274,9 @@ def apply_ep(params: dict, cfg: ModelConfig, x: jax.Array, mesh,
         None if shared is None else P(),
     )
     out_specs = (P(batch_axes, model_axis, None), P())
-    from repro.distributed.sharding import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )(x, params["router"], params["wg"], params["wu"], params["wd"], shared)
     return y, aux
 
@@ -316,9 +316,9 @@ def apply_ep_decode(params: dict, cfg: ModelConfig, x: jax.Array, mesh,
         None if shared is None else P(),
     )
     out_specs = (P(batch_axes, None, None), P())
-    from repro.distributed.sharding import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )(x, params["router"], params["wg"], params["wu"], params["wd"], shared)
     return y, aux
 

@@ -494,8 +494,10 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens, cache: dict, start,
 
 def init_paged_cache(cfg: ModelConfig, batch_size: int, max_len: int,
                      page_size: int, num_pages: int) -> dict:
-    """Paged serve cache: K/V live in shared pools [L, num_pages, page_size,
-    KVH, Dh] and each slot maps virtual positions through ``pages``
+    """Paged serve cache: K/V live in shared pools [L, num_pages, KVH,
+    page_size, Dh] (kv head outside the page, so the paged decode kernel's
+    per-head page block is a whole (page_size, Dh) tile) and each slot maps
+    virtual positions through ``pages``
     [B, max_pages] (i32; the ``num_pages`` sentinel marks unallocated
     entries — see serve/pages.py). ``pos`` semantics are identical to the
     dense cache. SSM has no length-indexed KV, so paging is a no-op and the
@@ -517,8 +519,8 @@ def init_paged_cache(cfg: ModelConfig, batch_size: int, max_len: int,
     return {
         "pos": jnp.zeros((batch_size,), jnp.int32),
         "pages": jnp.full((batch_size, maxp), num_pages, jnp.int32),
-        "k": jnp.zeros((n, num_pages, page_size, kvh, dh), dt),
-        "v": jnp.zeros((n, num_pages, page_size, kvh, dh), dt),
+        "k": jnp.zeros((n, num_pages, kvh, page_size, dh), dt),
+        "v": jnp.zeros((n, num_pages, kvh, page_size, dh), dt),
     }
 
 
@@ -536,7 +538,7 @@ def insert_slots_paged(cache: dict, src: dict, slots, lengths,
     tree) and must never be written through; the suffix scatter begins at
     the slot's first private (or copied-on-write) page."""
     k_pool, v_pool = cache["k"], cache["v"]
-    num_pages, ps = k_pool.shape[1], k_pool.shape[2]
+    num_pages, ps = k_pool.shape[1], k_pool.shape[3]
     num_slots, maxp = cache["pages"].shape
     slots = jnp.asarray(slots, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -554,8 +556,12 @@ def insert_slots_paged(cache: dict, src: dict, slots, lengths,
         ok = ok & (t[None, :] >= starts[:, None])
     page = jnp.where(ok, page, num_pages)
     off = jnp.broadcast_to(t % ps, page.shape)
-    k_pool = k_pool.at[:, page, off].set(src["k"].astype(k_pool.dtype))
-    v_pool = v_pool.at[:, page, off].set(src["v"].astype(v_pool.dtype))
+    # the split (page, off) index puts its [n, s_max] dims first
+    to_pool = lambda x: x.transpose(1, 2, 0, 3, 4)  # noqa: E731
+    k_pool = k_pool.at[:, page, :, off].set(to_pool(src["k"]).astype(
+        k_pool.dtype))
+    v_pool = v_pool.at[:, page, :, off].set(to_pool(src["v"]).astype(
+        v_pool.dtype))
     pos = cache["pos"].at[slots].set(lengths)
     return {**cache, "k": k_pool, "v": v_pool, "pos": pos}
 

@@ -618,8 +618,11 @@ class ServeEngine:
                                 0, num_pages - 1)                # [1, SL]
                 off = jnp.broadcast_to(t % ps, page.shape)
                 m = (t < start)[None, None, :, None, None]
-                scratch = {"k": jnp.where(m, k_pool[:, page, off], 0),
-                           "v": jnp.where(m, v_pool[:, page, off], 0)}
+                # the split (page, off) index puts its [1, SL] dims first
+                rows = lambda pool: pool[:, page, :, off].transpose(  # noqa: E731
+                    2, 0, 1, 3, 4)
+                scratch = {"k": jnp.where(m, rows(k_pool), 0),
+                           "v": jnp.where(m, rows(v_pool), 0)}
                 last0 = jnp.zeros((tokens.shape[0], nv), jnp.float32)
                 logits, scratch = model.prefill_chunk(
                     params, cfg, tokens, scratch, start, lengths, last0,

@@ -1,7 +1,7 @@
 """Shared KV page pool: host-side page-table allocator for the serve engine.
 
 The paged KV layout replaces each slot's dense ``max_len`` cache row with a
-pool of fixed-size pages (``[num_pages, page_size, heads, dim]`` K/V arrays
+pool of fixed-size pages (``[num_pages, heads, page_size, dim]`` K/V arrays
 per layer, see ``models/lm.init_paged_cache``) plus a per-slot page table
 mapping virtual position ``s`` to pool page ``table[slot, s // page_size]``.
 Serve cache memory then scales with *live tokens* (pages actually backing

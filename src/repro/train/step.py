@@ -153,14 +153,12 @@ def train_state_shapes(model_cfg: ModelConfig, seed: int = 0,
 
 def make_train_step(model_cfg: ModelConfig, sel_cfg: SelectConfig,
                     opt_cfg: OptimizerConfig, *, mesh=None,
-                    batch_axes=("data",), use_pallas: bool = False,
-                    donate: bool = True):
+                    batch_axes=("data",), donate: bool = True):
     """Shim -> methods/selection.py (kept for existing callers)."""
     from repro.methods.selection import SelectionMethod
     method = SelectionMethod(name=sel_cfg.policy, sel_cfg=sel_cfg)
     return method.make_step(model_cfg, opt_cfg, mesh=mesh,
-                            batch_axes=batch_axes, use_pallas=use_pallas,
-                            donate=donate)
+                            batch_axes=batch_axes, donate=donate)
 
 
 def make_lora_train_step(model_cfg: ModelConfig, opt_cfg: OptimizerConfig, *,

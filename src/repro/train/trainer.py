@@ -71,7 +71,7 @@ def _place_state(state, shardings):
 class Trainer:
     def __init__(self, tcfg: TrainConfig, *, mesh=None, batch_axes=("data",),
                  method: str | None = None, data_source=None,
-                 batch_shardings=None, on_straggler=None, use_pallas=False,
+                 batch_shardings=None, on_straggler=None,
                  prefetch_depth: int = 0):
         self.tcfg = tcfg
         self.mesh = mesh
@@ -112,7 +112,7 @@ class Trainer:
 
         self.step_fn = self.method.make_step(
             tcfg.model, tcfg.optimizer, mesh=mesh, batch_axes=batch_axes,
-            use_pallas=use_pallas, **step_kw)
+            **step_kw)
         self.data = data_source or data_loader.make_source(
             "synthetic_math", seq_len=tcfg.seq_len,
             global_batch=tcfg.global_batch, seed=tcfg.seed)

@@ -155,6 +155,25 @@ def test_async_swap_trajectory_bit_exact(policy):
     assert t_async.step_fn.apply._cache_size() == 1
 
 
+@pytest.mark.parametrize("residency", ["device", "banked"])
+def test_kernel_switch_trains_like_xla_path(residency):
+    """ModelConfig.use_pallas is the one switch: 'always' runs block
+    grad-norm and (banked) masked AdamW as kernels inside the trainer's
+    step and follows the XLA ('never') trajectory."""
+    runs = {}
+    for mode in ("never", "always"):
+        tcfg = _tcfg(residency, steps=4)
+        tcfg = tcfg.__class__(**{**tcfg.__dict__,
+                                 "model": TINY.replace(use_pallas=mode)})
+        tr = Trainer(tcfg)
+        runs[mode] = (tr.train().losses, tr.state["params"])
+    np.testing.assert_allclose(runs["always"][0], runs["never"][0],
+                               rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(runs["always"][1]),
+                    jax.tree.leaves(runs["never"][1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
 def test_banked_pallas_path_matches_dense_pallas():
     """Fused Pallas kernel on bank rows == dense Pallas on full leaves."""
     part = pmod.build_partition(TINY)

@@ -125,7 +125,6 @@ def test_grad_compression_error_feedback(multidevice):
     out = multidevice("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.distributed import sharding as shrd
 from repro.distributed.compression import (compressed_grad_sync,
                                            init_error_state,
                                            quantize_with_feedback)
@@ -147,9 +146,9 @@ mesh = jax.make_mesh((4,), ("data",))
 def body(g, e):
     return compressed_grad_sync({"g": g}, {"g": e}, mesh, axes=("data",))
 g_loc = jnp.arange(8.0)
-f = jax.jit(shrd.shard_map(body, mesh=mesh,
-                           in_specs=(P("data"), P("data")),
-                           out_specs=(P("data"), P("data"))))
+f = jax.jit(jax.shard_map(body, mesh=mesh,
+                          in_specs=(P("data"), P("data")),
+                          out_specs=(P("data"), P("data")), check_vma=False))
 synced, e2 = f(jnp.tile(g_loc, 4).reshape(32), jnp.zeros(32))
 np.testing.assert_allclose(np.asarray(synced["g"][:8]), np.asarray(g_loc))
 hlo = f.lower(jnp.zeros(32), jnp.zeros(32)).compile().as_text()

@@ -14,8 +14,10 @@ def _tol(dtype):
 
 
 class TestBlockGradNorm:
+    # (2, 70001): 547 lane rows -> a full 512-row block plus an overhanging
+    # tail block whose out-of-range rows must not count
     @pytest.mark.parametrize("shape", [(3, 100), (2, 64, 65), (5, 7, 9, 11),
-                                       (1, 2048), (4, 4096)])
+                                       (1, 2048), (4, 4096), (2, 70001)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_sweep(self, shape, dtype):
         g = (jax.random.normal(jax.random.PRNGKey(0), shape) * 2).astype(dtype)
@@ -33,7 +35,8 @@ class TestBlockGradNorm:
 
 
 class TestMaskedAdamW:
-    @pytest.mark.parametrize("shape", [(4, 100), (2, 32, 9), (3, 2048)])
+    @pytest.mark.parametrize("shape", [(4, 100), (2, 32, 9), (3, 2048),
+                                       (2, 70001)])
     @pytest.mark.parametrize("pdtype", [jnp.float32, jnp.bfloat16])
     def test_sweep(self, shape, pdtype):
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -200,8 +203,8 @@ class TestPagedDecodeAttention:
 
     def _pool(self, seed, num_pages, ps, kvh, d, dtype):
         ks = jax.random.split(jax.random.PRNGKey(seed), 2)
-        k = (0.5 * jax.random.normal(ks[0], (num_pages, ps, kvh, d))).astype(dtype)
-        v = (0.5 * jax.random.normal(ks[1], (num_pages, ps, kvh, d))).astype(dtype)
+        k = (0.5 * jax.random.normal(ks[0], (num_pages, kvh, ps, d))).astype(dtype)
+        v = (0.5 * jax.random.normal(ks[1], (num_pages, kvh, ps, d))).astype(dtype)
         return k, v
 
     @pytest.mark.parametrize("ps", [4, 8, 16])
@@ -248,8 +251,9 @@ class TestPagedDecodeAttention:
         o = ops.paged_decode_attention(q, k_pool, v_pool, jnp.asarray(tbl),
                                        jnp.asarray(vl), hmap)
         # dense view: gather pages row-major, expand kv heads via hmap
-        kd = k_pool[tbl].reshape(b, maxp * ps, kvh, d)[:, :, hmap, :]
-        vd = v_pool[tbl].reshape(b, maxp * ps, kvh, d)[:, :, hmap, :]
+        dense = lambda pool: pool[tbl].transpose(0, 1, 3, 2, 4).reshape(  # noqa: E731
+            b, maxp * ps, kvh, d)[:, :, hmap, :]
+        kd, vd = dense(k_pool), dense(v_pool)
         od = ops.decode_attention(q, kd, vd, jnp.asarray(vl))
         np.testing.assert_allclose(np.asarray(o, np.float32),
                                    np.asarray(od, np.float32),

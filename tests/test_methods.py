@@ -1,10 +1,13 @@
 """Fine-tuning method registry: resolution, smoke runs, golden-seed parity.
 
-The GOLDEN table was captured from the pre-refactor ``Trainer`` /
-``make_train_step`` code path (and, for LoRA, from the first deterministic
-revision — adapter init previously depended on per-process string-hash
-salting) on this exact tiny config. The parity test asserts the registry
-refactor reproduces those trajectories bit-for-bit-close.
+The GOLDEN table pins each method's 5-step trajectory on this exact tiny
+config (losses, a parameter fingerprint and the final selection mask). It
+was recaptured on 2026-10-16 under jax 0.9.0 (CPU), because parameter
+initialization under the jax 0.9 PRNG differs from the jax 0.4 line the
+previous table came from: every method's first loss moved from 3.947706 to
+3.814510 before any update ran. The capture ran the same Trainer code path
+on the commit before and after kernel bring-up and got identical values;
+the comparison tolerance is unchanged.
 """
 import jax
 import jax.numpy as jnp
@@ -27,28 +30,28 @@ ALL_METHODS = ("full", "adagradselect", "topk_grad", "random", "lora",
 # 5 steps, seed 0, on GOLDEN_MODEL (see module docstring)
 GOLDEN = {
     "adagradselect": {
-        "losses": [3.947706, 3.383842, 3.053774, 2.758202, 2.788784],
-        "fp": 4618.3515625,
+        "losses": [3.81451, 3.474582, 3.010671, 2.880186, 2.712668],
+        "fp": 4611.5908203125,
         "final_mask": [0, 1, 0, 0, 0, 1],
     },
     "topk_grad": {
-        "losses": [3.947706, 3.383842, 3.053774, 2.758202, 2.70422],
-        "fp": 4616.29443359375,
+        "losses": [3.81451, 3.474582, 3.008594, 2.856374, 2.746737],
+        "fp": 4612.943359375,
         "final_mask": [0, 1, 0, 0, 0, 1],
     },
     "random": {
-        "losses": [3.947706, 3.437435, 3.253561, 3.049162, 2.837551],
-        "fp": 4605.08447265625,
-        "final_mask": [0, 1, 0, 0, 1, 0],
+        "losses": [3.81451, 3.4462, 3.204596, 3.029714, 2.893373],
+        "fp": 4607.29248046875,
+        "final_mask": [0, 1, 0, 0, 0, 1],
     },
     "full": {
-        "losses": [3.947706, 3.291163, 2.890966, 2.702341, 2.628693],
-        "fp": 4652.72705078125,
+        "losses": [3.81451, 3.326064, 2.887514, 2.729954, 2.578287],
+        "fp": 4636.58154296875,
         "final_mask": [1, 1, 1, 1, 1, 1],
     },
     "lora": {
-        "losses": [3.947706, 3.402235, 3.240843, 3.049545, 2.876902],
-        "fp": 495.78143310546875,
+        "losses": [3.81451, 3.559687, 3.07767, 3.021049, 2.884983],
+        "fp": 489.8881530761719,
         "final_mask": None,
     },
 }

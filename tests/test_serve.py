@@ -253,6 +253,21 @@ def test_paged_engine_matches_dense_engine(arch):
     assert seng.stats["backpressure"] > 0
 
 
+def test_paged_kernel_engine_matches_dense_engine():
+    """use_pallas='always' sends paged decode through the Pallas kernel
+    (interpreted here, compiled on TPU): still token-exact vs the dense
+    engine on the mixed staggered workload."""
+    cfg = get_smoke_config("llama3.2-1b").replace(use_pallas="always")
+    params = _params(cfg)
+    mk = _mixed_requests(cfg)
+    kw = dict(max_len=40, num_slots=3, decode_chunk=4)
+    dense = _eng(cfg, params, **kw).run(mk())
+    paged = _eng(cfg, params, kv_layout="paged", page_size=8, **kw).run(mk())
+    for uid in dense:
+        np.testing.assert_array_equal(paged[uid], dense[uid],
+                                      err_msg=f"request {uid}")
+
+
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "qwen3-moe-30b-a3b"])
 @pytest.mark.parametrize("kv_layout", ["dense", "paged"])
 def test_chunked_prefill_matches_single_shot(arch, kv_layout):
@@ -641,11 +656,13 @@ def test_insert_slots_paged_routes_through_table():
                                 np.array([6, 8], np.int32))
     np.testing.assert_array_equal(np.asarray(out["pos"]), [0, 0, 6])
     # slot 2 row 0: positions 0..3 -> page 5, positions 4..5 -> page 1
+    # (pool pages are [KVH, page_size, Dh]; the prefill cache is [S, KVH, Dh])
+    heads_first = lambda x: np.asarray(x).transpose(0, 2, 1, 3)  # noqa: E731
     np.testing.assert_array_equal(np.asarray(out["k"][:, 5]),
-                                  np.asarray(src["k"][:, 0, :ps]))
-    np.testing.assert_array_equal(np.asarray(out["k"][:, 1, :2]),
-                                  np.asarray(src["k"][:, 0, ps:ps + 2]))
+                                  heads_first(src["k"][:, 0, :ps]))
+    np.testing.assert_array_equal(np.asarray(out["k"][:, 1, :, :2]),
+                                  heads_first(src["k"][:, 0, ps:ps + 2]))
     # positions >= length (6,7) dropped; row 1 (pad slot 3) dropped entirely
-    assert not np.asarray(out["k"][:, 1, 2:]).any()
+    assert not np.asarray(out["k"][:, 1, :, 2:]).any()
     for pg in (0, 2, 3, 4):
         assert not np.asarray(out["k"][:, pg]).any(), pg

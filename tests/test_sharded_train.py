@@ -266,6 +266,35 @@ print("OK async")
     assert "ASYNC_PARITY" in out
 
 
+def test_dp8_kernels_per_shard_match_xla_path(multidevice):
+    """use_pallas='always' on dp=8: GSPMD cannot partition a Pallas kernel,
+    so the step runs each kernel per shard (shard_map) in the layout of the
+    state it touches — ZeRO-1-sharded dense moments (layer axis split over
+    data) and replicated banks. Both follow the XLA path's trajectory and
+    both banked phases still compile once."""
+    out = multidevice(_DP8_PRELUDE + """
+import dataclasses
+for res in ("device", "banked"):
+    runs = {}
+    for mode in ("never", "always"):
+        t = tcfg(res, "zero1", steps=4)
+        t = dataclasses.replace(t, model=TINY.replace(use_pallas=mode))
+        tr = Trainer(t, mesh=mesh)
+        runs[mode] = (tr.train().losses, tr.state["params"])
+        if res == "banked":
+            assert tr.step_fn.forward_select._cache_size() == 1
+            assert tr.step_fn.apply._cache_size() == 1
+    np.testing.assert_allclose(runs["always"][0], runs["never"][0],
+                               atol=2e-5)
+    for a, b in zip(jax.tree.leaves(runs["always"][1]),
+                    jax.tree.leaves(runs["never"][1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    print("KERNEL_PARITY", res)
+print("OK kernels")
+""", num_devices=8, timeout=560)
+    assert "OK kernels" in out
+
+
 def test_dp8_sharded_checkpoint_roundtrip(multidevice):
     """banked + zero1 on dp=8: mid-run save, restore into a fresh trainer
     (store re-sharded onto the mesh), continue — identical params to the
