@@ -68,6 +68,21 @@ def _constrain(tree, shardings):
         tree, shardings)
 
 
+def _kernel_layout(mesh, state_shardings, use_pallas, *path):
+    """``(mesh, specs)`` the Pallas kernels run in: each runs per shard of
+    the state at ``state_shardings[path[0]][path[1]]...``, laid out as
+    ``specs``. ``(None, None)`` when the kernels are off or there is no
+    mesh; ``specs`` is None when the state has no sharding tree."""
+    if not use_pallas or mesh is None:
+        return None, None
+    if state_shardings is None:
+        return mesh, None
+    tree = state_shardings
+    for key in path:
+        tree = tree[key]
+    return mesh, jax.tree.map(lambda s: s.spec, tree)
+
+
 @dataclasses.dataclass(frozen=True)
 class SelectionMethod:
     """FinetuneMethod for block-masked fine-tuning under one policy."""
@@ -187,14 +202,8 @@ class SelectionMethod:
         """
         from repro.kernels import ops as kops
         use_pallas = kops.use_kernels(model_cfg)
-        kmesh = mesh if use_pallas else None
-        spec_of = lambda shardings: jax.tree.map(  # noqa: E731
-            lambda s: s.spec, shardings)
-        p_specs = m_specs = None
-        if kmesh is not None and state_shardings is not None:
-            p_specs = spec_of(state_shardings["params"])
-            if opt_cfg.moment_residency == "device":
-                m_specs = spec_of(state_shardings["opt"]["m"])
+        kmesh, p_specs = _kernel_layout(mesh, state_shardings, use_pallas,
+                                        "params")
         sel_cfg = self.sel_cfg
         model = model_registry.get(model_cfg)
         partition = part_mod.build_partition(model_cfg)
@@ -248,11 +257,12 @@ class SelectionMethod:
             return self._make_banked_step(
                 opt_cfg, partition, forward_select, step_metrics,
                 use_pallas=use_pallas, donate=donate,
-                state_shardings=state_shardings, mesh=mesh,
-                kernel_mesh=kmesh, p_specs=p_specs)
+                state_shardings=state_shardings, mesh=mesh)
         if opt_cfg.moment_residency != "device":
             raise ValueError(
                 f"unknown moment_residency {opt_cfg.moment_residency!r}")
+        _, m_specs = _kernel_layout(mesh, state_shardings, use_pallas,
+                                    "opt", "m")
 
         def step_fn(state, batch):
             grads, mask, sel_state, loss, metrics, gnorm, block_norms = \
@@ -271,9 +281,9 @@ class SelectionMethod:
 
     def _make_banked_step(self, opt_cfg, partition, forward_select,
                           step_metrics, *, use_pallas, donate,
-                          state_shardings=None, mesh=None, kernel_mesh=None,
-                          p_specs=None):
+                          state_shardings=None, mesh=None):
         shd = state_shardings
+        kmesh, p_specs = _kernel_layout(mesh, shd, use_pallas, "params")
 
         def fwd_fn(params, sel_state, batch):
             out = forward_select(params, sel_state, batch)
@@ -297,7 +307,7 @@ class SelectionMethod:
             lr = learning_rate(opt_cfg, step)
             params, banks, counts = masked_adamw.banked_update(
                 opt_cfg, partition, params, grads, banks, counts, mask, lr,
-                use_pallas=use_pallas, mesh=kernel_mesh, specs=p_specs)
+                use_pallas=use_pallas, mesh=kmesh, specs=p_specs)
             if shd is not None:
                 params = _constrain(params, shd["params"])
                 banks = _constrain(banks, shd["opt"]["banks"])
