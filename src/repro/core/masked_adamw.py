@@ -260,19 +260,12 @@ def _scatter_group_donated(leaves, slots, rows):
                  for l, r in zip(leaves, rows))
 
 
-def writeback_evictions(plans: list, banks: dict, store: dict) -> dict:
-    """Stream evicted blocks' bank rows back into the full store
-    (device -> store side). Reads the banks — so in the overlapped timeline
-    this runs only after phase B's bank output is ready (device_get blocks
-    on it, which is exactly the background thread's job). Host (numpy)
-    store leaves are updated in place; device leaves functionally. Returns
-    the (possibly new) store tree. Admitted blocks' store rows are disjoint
-    from evicted ones, so this commutes with ``prefetch_admissions``."""
-    from repro.core import offload
-    new_store = dict(store)
-    # dispatch every device-side row gather first (one fused call per
-    # group), then fetch them all with one batched device_get: a single
-    # host sync per boundary instead of a blocking round trip per bank leaf
+def gather_evictions(plans: list, banks: dict) -> list:
+    """Dispatch the device-side reads of evicted blocks' bank rows (one
+    fused gather per stacked group, the whole leaves of an unstacked one),
+    in the order ``writeback_evictions`` consumes them. Asynchronous: the
+    reads run once the banks are ready, behind whatever the device has
+    queued."""
     dev = []
     for plan in plans:
         if not len(plan.ev_blocks):
@@ -284,7 +277,21 @@ def writeback_evictions(plans: list, banks: dict, store: dict) -> dict:
                                      jnp.asarray(plan.ev_slots, jnp.int32)))
         else:
             dev.extend(leaves)
-    host = iter(jax.device_get(dev))
+    return dev
+
+
+def writeback_evictions(plans: list, rows: list, store: dict) -> dict:
+    """Stream evicted blocks' bank rows (``gather_evictions(plans, banks)``)
+    back into the full store (device -> store side), with one batched
+    ``device_get``: a single host sync per boundary, not a round trip per
+    bank leaf. In the overlapped timeline the rows are phase B's output,
+    so this runs on the background thread. Host (numpy) store leaves are
+    updated in place; device leaves functionally. Returns the (possibly
+    new) store tree. Admitted blocks' store rows are disjoint from evicted
+    ones, so this commutes with ``prefetch_admissions``."""
+    from repro.core import offload
+    new_store = dict(store)
+    host = iter(jax.device_get(rows))
     for plan in plans:
         if not len(plan.ev_blocks):
             continue
@@ -311,11 +318,12 @@ def prefetch_admissions(plans: list, store: dict, staging=None) -> dict:
     run any time after the plan exists, concurrent with phase B (the
     overlapped path's store->device prefetch). ``staging``: optional
     reusable host buffer pool (``core.swap.StagingPool``) so host-store
-    reads don't allocate fresh numpy staging on every boundary. Returns
+    reads don't allocate fresh numpy staging on every boundary. Returns,
+    once every host->device copy it started has landed,
     ``{key: {"m": [rows per leaf], "v": [...]}}`` in tree-flatten order."""
     from repro.core import offload
     staged = {}
-    pooled = []
+    copies = []
     for plan in plans:
         if not len(plan.ad_blocks):
             continue
@@ -332,33 +340,37 @@ def prefetch_admissions(plans: list, store: dict, staging=None) -> dict:
                         rows = offload.store_read_rows(sl, plan.ad_blocks,
                                                        out=buf)
                         dev = jax.device_put(rows)
-                        if buf is not None:
-                            pooled.append(dev)
+                        copies.append(dev)
                     else:
                         dev = offload.store_read_rows(sl, plan.ad_blocks)
+                elif isinstance(sl, np.ndarray):
+                    dev = jax.device_put(sl)
+                    copies.append(dev)
                 else:
-                    dev = (jax.device_put(sl) if isinstance(sl, np.ndarray)
-                           else jnp.asarray(sl))
+                    dev = jnp.asarray(sl)
                 rows_out.append(dev)
             group[mom] = rows_out
         staged[plan.key] = group
-    if pooled:
-        # pool buffers are reused next boundary; one sync for all transfers
-        # (not one per leaf) makes sure every transfer has read its buffer
-        jax.block_until_ready(pooled)
+    if copies:
+        # one sync for every host->device copy (not one per leaf): pool
+        # buffers are reused next boundary, and a caller's timing of this
+        # call then holds each copy whole
+        jax.block_until_ready(copies)
     return staged
 
 
 def commit_swap(plans: list, banks: dict, store: dict, slot_map,
                 staged: dict, donate: bool = False):
     """Apply a planned boundary: scatter staged admissions into bank rows,
-    mark evicted slots free, update ``slot_map``. Device work is a handful
-    of async scatter dispatches — with admissions prefetched and evictions
-    written back in the background, this is all that remains on the
-    critical path. ``donate=True`` donates the scattered bank leaves (rows
-    written in place instead of copying the whole bank) — only for callers
-    that drop their last reference to the input banks, i.e. the swap
-    planner's per-step boundary. Returns (banks, slot_map, store)."""
+    mark evicted slots free, update ``slot_map``. Device work is one async
+    scatter dispatch per changed group, and each changed group's ``slots``
+    vector is read back to the host (it blocks until the bank is ready).
+    With admissions prefetched and evictions written back in the
+    background, this and the join on that job are what the boundary leaves
+    on the critical path. ``donate=True`` donates the scattered bank leaves
+    (rows written in place instead of copying the whole bank) — only for
+    callers that drop their last reference to the input banks, i.e. the
+    swap planner's per-step boundary. Returns (banks, slot_map, store)."""
     from repro.core import offload
     slot_map = np.array(slot_map, np.int32)  # fresh copy per boundary
     new_banks = dict(banks)
@@ -415,7 +427,8 @@ def swap_banked(partition: BlockPartition, banks: dict, store: dict,
     store leaves are updated in place, device leaves functionally.
 
     This is the synchronous composition of the boundary's phases —
-    ``plan_swap`` -> ``prefetch_admissions`` -> ``writeback_evictions`` ->
+    ``plan_swap`` -> ``prefetch_admissions`` -> ``gather_evictions`` ->
+    ``writeback_evictions`` ->
     ``commit_swap``. The async planner (core/swap.py) runs the first three
     in the background against the *predicted* next selection while phase B
     and the next phase A compute, leaving only ``commit_swap`` on the
@@ -424,7 +437,7 @@ def swap_banked(partition: BlockPartition, banks: dict, store: dict,
     if not plans:
         return dict(banks), np.array(slot_map, np.int32), dict(store)
     staged = prefetch_admissions(plans, store, staging)
-    store = writeback_evictions(plans, banks, store)
+    store = writeback_evictions(plans, gather_evictions(plans, banks), store)
     return commit_swap(plans, banks, store, slot_map, staged)
 
 

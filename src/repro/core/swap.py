@@ -18,12 +18,22 @@ between steps. ``SwapPlanner`` combines both ideas:
   On a multi-device mesh the job runs *inline* on the dispatching thread
   instead — sharded store reads carry collectives, which deadlock if two
   threads enqueue them concurrently — still after phase B's async dispatch;
-* at step t+1's boundary ``resolve`` joins the thread. If the prediction
-  matched the real selection (all-or-nothing on the [k] indices vector),
-  only ``commit_swap`` remains on the critical path — and it donates the
-  scattered bank leaves, so XLA writes the staged rows in place instead of
-  copying each bank. A miss falls back to the synchronous ``swap_banked``
-  and is counted (``SwapStats.predicted_hit_rate``).
+* at step t+1's boundary ``resolve`` joins the thread (``swap_join``). If
+  the prediction matched the real selection (all-or-nothing on the [k]
+  indices vector), ``commit_swap`` (``swap_commit``) runs next — it donates
+  the scattered bank leaves, so XLA writes the staged rows in place
+  instead of copying each bank. The join is not free. The job learns the
+  predicted indices only once phase B is done, and by then the training
+  thread has queued the next phase A, so the evicted rows' gathers run
+  after that phase A and their copy to the host (``swap_evict``, on the
+  planner's track) starts only when it ends: the device idles while the
+  training thread waits for the copy. On one TPU v5e with the host store
+  (qwen2.5-0.5b, 8x1024 rows, every boundary predicted) the gathers came
+  ready within a few ms of phase A's end, the copy ran at about 3 GB/s,
+  and the join, ending with it, held about 90% of the boundary's host
+  time (the commit the rest). Gathers dispatched ahead of phase A would
+  let the copy overlap it. A miss falls back to the synchronous swap
+  (``swap_sync``) and is counted (``SwapStats.predicted_hit_rate``).
 
 Why the overlap cannot corrupt state:
 
@@ -80,6 +90,10 @@ class StagingPool:
         return sum(b.nbytes for b in self._bufs.values())
 
 
+def _hist():
+    return dataclasses.field(default_factory=obs.Histogram, repr=False)
+
+
 @dataclasses.dataclass
 class SwapStats:
     """Boundary accounting + step-phase timing for the banked driver.
@@ -87,24 +101,40 @@ class SwapStats:
     ``predicted_hits`` those fully absorbed by the background dispatch;
     ``sync_swaps`` the fallback (mispredict, overflow-on-predicted-plan, or
     async disabled). Phase timing lives in obs histograms — the one timing
-    source of truth (the banked driver times each phase once via
-    ``obs.timed`` and trace spans ride the same measurement): ``phase_a``
-    includes the forward/select device wait (the indices sync), ``swap``
-    the boundary resolve+commit (or the full synchronous swap), ``phase_b``
-    the apply + dispatch issue. The historical accumulated-µs fields
-    (``phase_a_us`` etc., the bench JSON schema) are read-only views over
-    those histograms' totals."""
+    source of truth (each is timed once via ``obs.timed`` and its trace
+    span rides the same measurement), in µs:
+
+    * ``phase_a``: forward/select dispatch and, inside it, ``index_sync``
+      (the wait for the [k] indices);
+    * ``swap``: the boundary, holding ``join`` (waiting for the planner's
+      job) and ``commit`` (``commit_swap`` on a hit, empty plans included),
+      or ``sync`` (the synchronous swap of a miss);
+    * ``phase_b``: the apply + dispatch issue;
+    * ``admit`` / ``evict``: the moment copies store->device and
+      device->store, on the planner's thread or inside ``sync``; ``evict``
+      starts once the evicted rows' gathers have run on the device.
+
+    ``admit_bytes`` / ``evict_bytes`` count the moment bytes each direction
+    moved. The historical accumulated-µs fields (``phase_a_us`` etc., the
+    bench JSON schema) are read-only views over the histograms' totals."""
     steps: int = 0
     boundaries: int = 0
     predicted_hits: int = 0
     sync_swaps: int = 0
     dispatches: int = 0
-    phase_a: obs.Histogram = dataclasses.field(
-        default_factory=obs.Histogram, repr=False)
-    swap: obs.Histogram = dataclasses.field(
-        default_factory=obs.Histogram, repr=False)
-    phase_b: obs.Histogram = dataclasses.field(
-        default_factory=obs.Histogram, repr=False)
+    phase_a: obs.Histogram = _hist()
+    index_sync: obs.Histogram = _hist()
+    swap: obs.Histogram = _hist()
+    join: obs.Histogram = _hist()
+    commit: obs.Histogram = _hist()
+    sync: obs.Histogram = _hist()
+    admit: obs.Histogram = _hist()
+    evict: obs.Histogram = _hist()
+    phase_b: obs.Histogram = _hist()
+    admit_bytes: obs.Counter = dataclasses.field(
+        default_factory=obs.Counter, repr=False)
+    evict_bytes: obs.Counter = dataclasses.field(
+        default_factory=obs.Counter, repr=False)
 
     @property
     def phase_a_us(self) -> float:
@@ -129,7 +159,9 @@ class SwapStats:
                 "dispatches": self.dispatches,
                 "phase_a_us": self.phase_a_us, "swap_us": self.swap_us,
                 "phase_b_us": self.phase_b_us,
-                "predicted_hit_rate": self.predicted_hit_rate}
+                "predicted_hit_rate": self.predicted_hit_rate,
+                "admit_bytes": self.admit_bytes.value,
+                "evict_bytes": self.evict_bytes.value}
 
 
 class SwapPlanner:
@@ -154,13 +186,11 @@ class SwapPlanner:
         self.stats = SwapStats()
         # last-planner-wins registry bindings: the active trainer's phase
         # histograms and boundary counters show up in obs.snapshot()
-        for name, hist in (("phase_a_us", self.stats.phase_a),
-                           ("swap_us", self.stats.swap),
-                           ("phase_b_us", self.stats.phase_b)):
-            obs.metrics.register(name, hist, subsystem="swap")
+        for name in ("phase_a", "index_sync", "swap", "join", "commit",
+                     "sync", "admit", "evict", "phase_b"):
+            obs.metrics.register(f"{name}_us", getattr(self.stats, name),
+                                 subsystem="swap")
         obs.metrics.register("banked", self.stats.as_dict, subsystem="swap")
-        self._c_mispredicts = obs.metrics.counter("mispredicts",
-                                                  subsystem="swap")
         self._pool: ThreadPoolExecutor | None = None
         self._pending = None  # Future | dict -> dict | None
         self._predict = jax.jit(
@@ -193,8 +223,7 @@ class SwapPlanner:
                     # predicted selection overflows the banks — the real one
                     # may not (or will raise on the sync path with context)
                     return {"idx": idx, "failed": True}
-                staged = ma.prefetch_admissions(plans, store, self.staging)
-                new_store = ma.writeback_evictions(plans, banks, store)
+                staged, new_store = self._move(plans, banks, store)
                 return {"idx": idx, "failed": False, "plans": plans,
                         "staged": staged, "store": new_store}
 
@@ -219,38 +248,59 @@ class SwapPlanner:
         reader. A miss falls back to the synchronous ``swap_banked`` path
         (same donation, pooled staging). Returns (banks, slot_map, store)."""
         idx = np.asarray(indices)
-        job = self._take_pending()
+        with obs.timed(self.stats.join, "swap_join"):
+            job = self._take_pending()
         if job is not None and not job["failed"] \
                 and np.array_equal(job["idx"], idx):
             if job["plans"]:  # unchanged selections are not boundaries
                 self.stats.boundaries += 1
                 self.stats.predicted_hits += 1
-            return ma.commit_swap(job["plans"], banks, job["store"],
-                                  slot_map, job["staged"], donate=True)
+            with obs.timed(self.stats.commit, "swap_commit"):
+                return ma.commit_swap(job["plans"], banks, job["store"],
+                                      slot_map, job["staged"], donate=True)
         if job is not None:
             # keep the job's store: predicted-eviction writebacks are inert
             # for still-resident blocks and identical for real evictions
             store = job["store"] if not job["failed"] else store
-        mask = np.zeros((self.num_blocks,), bool)
-        mask[idx[idx < self.num_blocks]] = True
-        plans = ma.plan_swap(self.partition, slot_map, mask,
-                             ma.bank_caps(banks))
-        if not plans:
-            return dict(banks), np.array(slot_map, np.int32), dict(store)
-        self.stats.boundaries += 1
-        self.stats.sync_swaps += 1
-        if job is not None:
-            # a dispatch was in flight but missed (or overflowed): count it
-            # where latency diagnosis looks first
-            self._c_mispredicts.inc()
-            obs.instant("swap_mispredict",
-                        {"predicted": job["idx"].tolist(),
-                         "actual": idx.tolist()} if not job["failed"]
-                        else {"failed_plan": True})
-        staged = ma.prefetch_admissions(plans, store, self.staging)
-        store = ma.writeback_evictions(plans, banks, store)
-        return ma.commit_swap(plans, banks, store, slot_map, staged,
-                              donate=True)
+        with obs.timed(self.stats.sync, "swap_sync"):
+            mask = np.zeros((self.num_blocks,), bool)
+            mask[idx[idx < self.num_blocks]] = True
+            plans = ma.plan_swap(self.partition, slot_map, mask,
+                                 ma.bank_caps(banks))
+            if not plans:
+                return dict(banks), np.array(slot_map, np.int32), dict(store)
+            self.stats.boundaries += 1
+            self.stats.sync_swaps += 1
+            if job is not None:
+                # a dispatch was in flight but missed (or overflowed)
+                obs.instant("swap_mispredict",
+                            {"predicted": job["idx"].tolist(),
+                             "actual": idx.tolist()} if not job["failed"]
+                            else {"failed_plan": True})
+            staged, store = self._move(plans, banks, store)
+            return ma.commit_swap(plans, banks, store, slot_map, staged,
+                                  donate=True)
+
+    def _move(self, plans: list, banks: dict, store: dict):
+        """The boundary's copies for ``plans``: admitted blocks' moments
+        store->device, then evicted ones device->store, each timed and its
+        bytes counted. Returns (staged admissions, store)."""
+        admit = _moved(plans, store, "ad_blocks")
+        evict = _moved(plans, store, "ev_blocks")
+        # dispatch the evicted rows' gathers before the admissions' copies;
+        # they run once phase B's banks are ready, behind whatever the
+        # device has queued by then
+        rows = ma.gather_evictions(plans, banks)
+        with obs.timed(self.stats.admit, "swap_admit", admit):
+            staged = ma.prefetch_admissions(plans, store, self.staging)
+        # wait for the gathers outside the span (the device_get inside
+        # would block on them anyway), so it times the copy to the host
+        jax.block_until_ready(rows)
+        with obs.timed(self.stats.evict, "swap_evict", evict):
+            store = ma.writeback_evictions(plans, rows, store)
+        self.stats.admit_bytes.inc(sum(admit["bytes"]))
+        self.stats.evict_bytes.inc(sum(evict["bytes"]))
+        return staged, store
 
     # ------------------------------------------------------------- barrier
 
@@ -272,3 +322,20 @@ class SwapPlanner:
         if job is None or isinstance(job, dict):  # inline jobs store results
             return job
         return job.result()
+
+
+def _moved(plans: list, store: dict, side: str) -> dict:
+    """Per group that moves blocks on ``side`` (``"ad_blocks"`` or
+    ``"ev_blocks"``): its key, the blocks and their moment bytes (first
+    and second moments, from the store's row sizes)."""
+    out = {"group": [], "blocks": [], "bytes": []}
+    for plan in plans:
+        n = len(getattr(plan, side))
+        if not n:
+            continue
+        row = sum(leaf.nbytes // (leaf.shape[0] if plan.stacked else 1)
+                  for leaf in jax.tree.leaves(store[plan.key]))
+        out["group"].append(plan.key)
+        out["blocks"].append(n)
+        out["bytes"].append(n * row)
+    return out

@@ -23,8 +23,6 @@ from __future__ import annotations
 import queue
 import threading
 
-from repro import obs
-
 _DONE = object()
 
 
@@ -41,12 +39,6 @@ class Prefetcher:
         self.depth = depth
         self._err: BaseException | None = None
         self._thread = None
-        # prefetch-depth occupancy: how many placed batches were waiting
-        # when the consumer arrived (depth sustained = producer keeps up)
-        self._g_occupancy = obs.metrics.gauge("prefetch_occupancy",
-                                              subsystem="data")
-        self._c_batches = obs.metrics.counter("prefetch_batches",
-                                              subsystem="data")
         if depth > 0:
             self._q: queue.Queue = queue.Queue(maxsize=depth)
             self._stop = threading.Event()
@@ -84,16 +76,13 @@ class Prefetcher:
     def __next__(self):
         if self._thread is None:  # synchronous mode
             batch, cursor = next(self._stream)
-            self._c_batches.inc()
             return self._place(batch), cursor
-        self._g_occupancy.set(self._q.qsize())
         item = self._q.get()
         if item is _DONE:
             if self._err is not None:
                 err, self._err = self._err, None
                 raise err
             raise StopIteration
-        self._c_batches.inc()
         return item
 
     def close(self):

@@ -340,15 +340,16 @@ class SelectionMethod:
                 # The policy's static-shape [k] indices vector is the one
                 # host sync the paper's design pays (k ids, not a
                 # [num_blocks] mask).
-                idx = np.asarray(sel_state["indices"])
+                with obs.timed(stats.index_sync, "index_sync"):
+                    idx = np.asarray(sel_state["indices"])
             opt = state["opt"]
             with obs.timed(stats.swap, "swap"):
                 store = offload.ensure_store_residency(opt["store"],
                                                        opt_cfg.offload,
                                                        shardings=store_sh)
-                # joins any in-flight dispatch; a prediction hit leaves only
-                # the commit (a few async scatters) on the critical path, a
-                # miss falls back to the synchronous swap (counted in stats)
+                # joins any in-flight dispatch (swap_join), then commits a
+                # prediction hit (swap_commit) or runs the synchronous swap
+                # of a miss (swap_sync, counted in stats)
                 banks, slot_map, store = planner.resolve(
                     idx, opt["banks"], store, opt["slot_map"])
             with obs.timed(stats.phase_b, "phase_b"):

@@ -12,10 +12,19 @@ Two layers with different cost contracts:
   returns a shared no-op context manager until ``obs.enable()`` installs a
   ``Tracer``; instrumentation sites that would force a host sync (reading
   a device mask, per-request timestamps into trace tracks) guard on
-  ``obs.enabled()``. Disabled mode therefore adds **no host syncs and no
-  measurable step-time cost** — step trajectories are bit-identical with
-  obs on or off (pinned in tests), and the ``obs_overhead`` bench row
-  regression-gates the disabled-mode cost at 3%.
+  ``obs.enabled()``. Disabled mode therefore adds **no host syncs** —
+  step trajectories are bit-identical with obs on or off (pinned in
+  tests). The ``obs_overhead`` row of the CPU ``benchmarks/`` compares
+  obs-on with obs-off steps on XLA:CPU, a regression guard of that
+  harness and not a device measurement; PERF.md gives what tracing costs
+  on the chip.
+
+``disable`` keeps the tracer it removes (``last_tracer``), so a finished
+run's spans can be read after tracing is off: ``Tracer.spans`` pairs them.
+Once ``count_compiles`` has run (the trainer calls it), the registry also
+holds ``jax/compiles``, one per program the process compiles or loads from
+JAX's persistent cache, with a ``compile`` instant on the timeline when
+tracing is on.
 
 Typical wiring (see train/trainer.py, core/swap.py, serve/engine.py):
 
@@ -44,6 +53,7 @@ from repro.obs.trace import (NOOP_SPAN, Tracer,  # noqa: F401
 metrics = MetricsRegistry()
 
 _tracer: Tracer | None = None
+_last: Tracer | None = None
 _selection: SelectionTrace | None = None
 
 
@@ -64,13 +74,20 @@ def enable(*, jax_profiler: bool = False, selection: bool = True,
 
 
 def disable() -> None:
-    global _tracer, _selection
+    global _tracer, _last, _selection
+    if _tracer is not None:
+        _last = _tracer
     _tracer = None
     _selection = None
 
 
 def tracer() -> Tracer | None:
     return _tracer
+
+
+def last_tracer() -> Tracer | None:
+    """The tracer ``disable`` last removed: its events stay readable."""
+    return _last
 
 
 def selection_trace() -> SelectionTrace | None:
@@ -139,3 +156,33 @@ def export_trace(path: str) -> None:
         raise RuntimeError("obs.export_trace: tracing is not enabled "
                            "(call obs.enable() before the run)")
     _tracer.export(path)
+
+
+# JAX records this duration around every program it compiles or loads from
+# the persistent cache, with the cache on or off (jax/_src/interpreters/
+# pxla.py, ``dispatch.BACKEND_COMPILE_EVENT``): one event per program
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    metrics.counter("compiles", subsystem="jax").inc()
+    tr = _tracer
+    if tr is not None:
+        tr.instant("compile", {"fun": kw.get("fun_name"), "s": duration})
+
+
+_counting_compiles = False
+
+
+def count_compiles() -> None:
+    """Start ``jax/compiles`` (once per process; later calls do nothing).
+    The trainer calls it, so importing ``repro.obs`` adds no JAX listener."""
+    global _counting_compiles
+    if _counting_compiles:
+        return
+    import jax
+    metrics.counter("compiles", subsystem="jax")
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    _counting_compiles = True

@@ -167,6 +167,26 @@ class Tracer:
         with self._lock:
             return list(self._events)
 
+    def spans(self, name: str) -> list[dict]:
+        """Finished spans called ``name``, each ``{"tid", "ts", "dur",
+        "args"}`` in microseconds: B/E pairs matched per thread, and X
+        events. A span still open is left out."""
+        out, stacks = [], {}
+        for ev in self.events():
+            ph = ev["ph"]
+            if ph == "B":
+                stacks.setdefault(ev["tid"], []).append(ev)
+            elif ph == "E" and stacks.get(ev["tid"]):
+                b = stacks[ev["tid"]].pop()
+                if b["name"] == name:
+                    out.append({"tid": b["tid"], "ts": b["ts"],
+                                "dur": ev["ts"] - b["ts"],
+                                "args": b.get("args", {})})
+            elif ph == "X" and ev["name"] == name:
+                out.append({"tid": ev["tid"], "ts": ev["ts"],
+                            "dur": ev["dur"], "args": ev.get("args", {})})
+        return out
+
     def export(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump({"traceEvents": self.events(),

@@ -73,6 +73,7 @@ class Trainer:
                  method: str | None = None, data_source=None,
                  batch_shardings=None, on_straggler=None,
                  prefetch_depth: int = 0):
+        obs.count_compiles()  # jax/compiles: every program from here on
         self.tcfg = tcfg
         self.mesh = mesh
         self.method_name = method or tcfg.method
@@ -131,6 +132,10 @@ class Trainer:
         self._m_stragglers = obs.metrics.counter("stragglers",
                                                  subsystem="train")
         self._m_loss = obs.metrics.gauge("last_loss", subsystem="train")
+        # time the loop waits for its next batch: near zero while the
+        # prefetcher keeps ahead of the device
+        self._m_data_wait = obs.metrics.histogram("data_wait_us",
+                                                  subsystem="data")
 
     # ------------------------------------------------------------- resume
     def maybe_restore(self) -> int:
@@ -202,7 +207,8 @@ class Trainer:
     def _train_loop(self, tcfg, fetch, step0, steps, last, pending, t0):
         sel_trace = obs.selection_trace()
         for step in range(step0, step0 + steps):
-            batch, self._data_cursor = next(fetch)
+            with obs.timed(self._m_data_wait, "data_wait"):
+                batch, self._data_cursor = next(fetch)
             if not pending:
                 t0 = time.perf_counter()
             with obs.span("train_step", {"step": step} if obs.enabled()

@@ -2,12 +2,18 @@
 counter/histogram thread-safety, trace-event well-formedness, the
 obs-on/obs-off bit-identical-trajectory contract, selection telemetry
 agreeing with optimizer counts, the exploration->exploitation report, the
-banked/serve trace structure, and the serve engine's consolidated
+banked/serve trace structure (the banked boundary's nested spans and its
+byte counters), the compile counter, and the serve engine's consolidated
 ``stats_snapshot()`` (including the decode_steps accounting)."""
 import json
+import os
+import subprocess
+import sys
 import threading
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_shim import given, settings, st
@@ -238,6 +244,171 @@ def test_banked_train_trace_has_phases_and_swap_thread(tmp_path):
                     and e["args"]["name"].startswith("swap-planner")}
     assert any(e["ph"] == "B" and e["name"] == "swap_dispatch_job"
                and e["tid"] in planner_tids for e in events)
+
+
+def _parents(events) -> dict:
+    """{span name: names of the spans directly around it on its thread,
+    None where it opens at the top}."""
+    stacks, out = {}, {}
+    for e in events:
+        if e["ph"] == "B":
+            stack = stacks.setdefault(e["tid"], [])
+            out.setdefault(e["name"], set()).add(stack[-1] if stack else None)
+            stack.append(e["name"])
+        elif e["ph"] == "E":
+            stacks[e["tid"]].pop()
+    return out
+
+
+def test_banked_trace_nests_the_boundary_spans(tmp_path):
+    obs.enable()
+    Trainer(_tcfg(residency="banked", steps=6, async_swap=True)).train()
+    path = tmp_path / "train.json"
+    obs.export_trace(str(path))
+    events = validate_trace_file(str(path))
+    parents = _parents(events)
+    assert parents["index_sync"] == {"phase_a"}
+    assert parents["swap_join"] == {"swap"}
+    assert parents["swap_commit"] == {"swap"}  # the prediction hits
+    assert parents["swap_sync"] == {"swap"}    # the first step has no job
+    assert parents["data_wait"] == {None}      # before train_step opens
+    planner = {e["tid"] for e in events if e["ph"] == "M"
+               and e["args"]["name"].startswith("swap-planner")}
+    for name in ("swap_admit", "swap_evict"):
+        assert parents[name] <= {"swap_dispatch_job", "swap_sync"}
+        assert any(e["ph"] == "B" and e["name"] == name
+                   and e["tid"] in planner for e in events), name
+        args = [e["args"] for e in events
+                if e["ph"] == "B" and e["name"] == name]
+        assert all(set(a) == {"group", "blocks", "bytes"} for a in args)
+
+
+@pytest.mark.parametrize("async_swap", [True, False])
+def test_swap_bytes_are_the_rows_of_the_plans_that_ran(monkeypatch,
+                                                       async_swap):
+    from repro.core import masked_adamw as ma
+    ran = []
+    prefetch = ma.prefetch_admissions
+
+    def recorded(plans, store, staging=None):
+        ran.extend(plans)
+        return prefetch(plans, store, staging)
+
+    monkeypatch.setattr(ma, "prefetch_admissions", recorded)
+    tr = Trainer(_tcfg(residency="banked", steps=6, async_swap=async_swap))
+    tr.train()
+
+    def row_bytes(plan):
+        # a block's float32 first and second moments, from the params
+        leaves = jax.tree.leaves(tr.state["params"][plan.key])
+        return 2 * 4 * sum(int(np.prod(x.shape[1:] if plan.stacked
+                                       else x.shape)) for x in leaves)
+
+    stats = tr.step_fn.swap_stats
+    admit = sum(len(p.ad_blocks) * row_bytes(p) for p in ran)
+    evict = sum(len(p.ev_blocks) * row_bytes(p) for p in ran)
+    assert admit > 0 and evict > 0
+    assert stats.admit_bytes.value == admit
+    assert stats.evict_bytes.value == evict
+    assert stats.admit.count == stats.evict.count > 0
+
+
+def test_evictions_are_gathered_first_and_copied_once_ready(monkeypatch):
+    """The planner dispatches the evicted rows' gathers before the
+    admissions, and ``swap_evict`` (``writeback_evictions``) starts with
+    them ready, so its time holds the copy to the host and not the wait."""
+    from repro.core import masked_adamw as ma
+    calls = []
+    gather, prefetch, store = (ma.gather_evictions, ma.prefetch_admissions,
+                               ma.writeback_evictions)
+
+    def gathered(plans, banks):
+        calls.append("gather")
+        return gather(plans, banks)
+
+    def prefetched(plans, st, staging=None):
+        calls.append("admit")
+        return prefetch(plans, st, staging)
+
+    def stored(plans, rows, st):
+        calls.append("evict")
+        assert all(r.is_ready() for r in rows)
+        return store(plans, rows, st)
+
+    monkeypatch.setattr(ma, "gather_evictions", gathered)
+    monkeypatch.setattr(ma, "prefetch_admissions", prefetched)
+    monkeypatch.setattr(ma, "writeback_evictions", stored)
+    Trainer(_tcfg(residency="banked", steps=6, async_swap=True)).train()
+    assert calls and calls == ["gather", "admit", "evict"] * (len(calls) // 3)
+
+
+def test_last_tracer_keeps_the_finished_spans():
+    tr = obs.enable()
+    with obs.span("outer", {"bytes": [3]}):
+        with obs.span("inner"):
+            pass
+    with obs.span("open"):
+        obs.disable()
+    assert obs.tracer() is None and obs.last_tracer() is tr
+    (outer,), (inner,) = tr.spans("outer"), tr.spans("inner")
+    assert outer["args"] == {"bytes": [3]} and inner["args"] == {}
+    assert outer["ts"] <= inner["ts"] and outer["dur"] >= inner["dur"] >= 0
+    assert tr.spans("absent") == []
+    obs.disable()  # a second disable keeps the last tracer
+    assert obs.last_tracer() is tr
+
+
+def test_compiles_counts_each_program_once_and_marks_the_timeline():
+    obs.count_compiles()
+    obs.count_compiles()  # a second call adds no second listener
+    compiles = obs.metrics.counter("compiles", subsystem="jax")
+    x, y = jnp.arange(7.0), jnp.arange(5.0)
+    f = jax.jit(lambda v: v * 3.0 + 1.0)
+    n0 = compiles.value
+    f(x).block_until_ready()
+    assert compiles.value == n0 + 1
+    f(x).block_until_ready()  # the same shape: no new program
+    assert compiles.value == n0 + 1
+    tr = obs.enable()
+    f(y).block_until_ready()
+    assert compiles.value == n0 + 2
+    marks = [e for e in tr.events() if e["ph"] == "i"]
+    assert [e["name"] for e in marks] == ["compile"]
+    assert marks[0]["args"]["s"] > 0
+
+
+_CACHED = """
+import jax, jax.numpy as jnp
+from repro import obs
+obs.count_compiles()
+asked = []
+jax.monitoring.register_event_listener(lambda name, **kw: asked.append(
+    name) if name == "/jax/compilation_cache/compile_requests_use_cache"
+    else None)
+x = jnp.ones((4,))
+for f in (lambda v: v + 1, lambda v: v * 2):
+    jax.jit(f)(x).block_until_ready()
+print(obs.metrics.counter("compiles", subsystem="jax").value, len(asked))
+"""
+
+
+def test_compiles_agree_with_the_persistent_cache_requests(tmp_path):
+    """With JAX's persistent cache on, a miss (first process) and a hit
+    (second) each count once, as the cache's own request event does."""
+    import repro
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+           "PYTHONPATH": str(Path(repro.__file__).parents[1])}
+    counts = []
+    for _ in range(2):
+        p = subprocess.run([sys.executable, "-c", _CACHED], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-2000:]
+        counts.append(tuple(map(int, p.stdout.split())))
+    assert counts[0] == counts[1]
+    assert counts[0][0] == counts[0][1] > 0
+    assert any((tmp_path / "cache").iterdir())
 
 
 # -------------------------------------------------------- selection report

@@ -181,7 +181,8 @@ def test_decomposed_boundary_equals_swap_banked():
                                    masked_adamw.bank_caps(banks))
     staged = masked_adamw.prefetch_admissions(plans, store,
                                               swap.StagingPool())
-    store2 = masked_adamw.writeback_evictions(plans, banks, store)
+    store2 = masked_adamw.writeback_evictions(
+        plans, masked_adamw.gather_evictions(plans, banks), store)
     b_new, sm_new, st_new = masked_adamw.commit_swap(plans, banks, store2,
                                                      slot_map, staged)
     np.testing.assert_array_equal(sm_new, sm_ref)
