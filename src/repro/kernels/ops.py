@@ -146,23 +146,34 @@ def banked_masked_adamw(p, g, m, v, slots, sel, counts, lr, b1, b2, eps, wd,
         p, g, m, v, slots, sel.reshape(cap), counts.reshape(cap), lr)
 
 
-def flash_attention(q, k, v, *, causal=True, segment_ids=None):
+def flash_attention(q, k, v, *, causal=True, segment_ids=None, mesh=None,
+                    batch_axes=("data",)):
     """q,k,v: [B, S, H, D] (layer layout; kv already head-expanded) ->
     [B, S, H, D]. ``segment_ids``: optional [B, S] packed segment ids
-    (0 = pad) — attention is block-diagonal over equal segments (the
-    segment-masked kernel; ids are repeated over the folded head axis)."""
+    (0 = pad): attention is block-diagonal over equal segments. Blocks
+    follow S and D (``flash_attention.block_sizes``). On a multi-device
+    ``mesh`` each device runs the kernel on its shard: the batch split over
+    ``batch_axes``, the heads over ``model`` where they divide."""
     b, s, h, d = q.shape
-    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, d)  # noqa: E731
-    bq = min(_fa.DEFAULT_BQ, s)
-    bk = min(_fa.DEFAULT_BK, s)
-    if segment_ids is None:
-        o = _fa.flash_attention(fold(q), fold(k), fold(v), causal, bq, bk,
-                                _interpret())
-    else:
-        seg = jnp.repeat(jnp.asarray(segment_ids, jnp.float32), h, axis=0)
-        o = _fa.flash_attention_segmented(fold(q), fold(k), fold(v), seg,
-                                          seg, causal, bq, bk, _interpret())
-    return o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    bq, bk = _fa.block_sizes(s, d)
+    interpret = _interpret()
+
+    def local(q, k, v, *seg):
+        bl, hl = q.shape[0], q.shape[2]
+        fold = lambda t: t.transpose(0, 2, 1, 3).reshape(bl * hl, s, d)  # noqa: E731
+        seg = jnp.asarray(seg[0], jnp.int32) if seg else None
+        o = _fa.flash_attention(fold(q), fold(k), fold(v), seg, hl, bq, bk,
+                                causal, interpret)
+        return o.reshape(bl, hl, s, d).transpose(0, 2, 1, 3)
+
+    args = (q, k, v) if segment_ids is None else (q, k, v, segment_ids)
+    heads = None
+    if _multi_device(mesh) and "model" in mesh.axis_names \
+            and h % mesh.shape["model"] == 0:
+        heads = "model"
+    spec = P(batch_axes, None, heads, None)
+    in_specs = (spec,) * 3 + ((P(batch_axes, None),) if len(args) > 3 else ())
+    return _per_shard(local, mesh, in_specs, spec)(*args)
 
 
 def decode_attention(q, k, v, valid_len):

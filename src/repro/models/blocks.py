@@ -30,14 +30,16 @@ def attn_block_init(key: jax.Array, cfg: ModelConfig, d_ff: int = 0) -> dict:
 
 
 def attn_block_apply(params, cfg: ModelConfig, x, *, prefix_len=0, chunk_q=512,
-                     positions=None, segment_ids=None):
+                     positions=None, segment_ids=None, mesh=None,
+                     batch_axes=("data",)):
     h = norms.apply(params["ln1"], x, cfg.norm_eps)
     if cfg.use_mla:
         h = mla.apply(params["attn"], cfg, h, chunk_q=chunk_q)
     else:
         h = attention.apply(params["attn"], cfg, h, prefix_len=prefix_len,
                             chunk_q=chunk_q, positions=positions,
-                            segment_ids=segment_ids)
+                            segment_ids=segment_ids, mesh=mesh,
+                            batch_axes=batch_axes)
     x = x + h
     h = norms.apply(params["ln2"], x, cfg.norm_eps)
     x = x + mlp.apply(params["mlp"], cfg, h)
@@ -122,7 +124,8 @@ def moe_block_apply(params, cfg: ModelConfig, x, *, mesh=None,
         h = mla.apply(params["attn"], cfg, h, chunk_q=chunk_q)
     else:
         h = attention.apply(params["attn"], cfg, h, chunk_q=chunk_q,
-                            positions=positions, segment_ids=segment_ids)
+                            positions=positions, segment_ids=segment_ids,
+                            mesh=mesh, batch_axes=batch_axes)
     x = x + h
     h = norms.apply(params["ln2"], x, cfg.norm_eps)
     y, aux = moe.apply(params["moe"], cfg, h, mesh=mesh, batch_axes=batch_axes)

@@ -77,7 +77,9 @@ def _enc_kv(p_attn, cfg: ModelConfig, enc_out):
 
 def dec_block_apply(p_l, cfg: ModelConfig, x, enc_out):
     h = norms.apply(p_l["ln1"], x, cfg.norm_eps)
-    h = attention.apply(p_l["self_attn"], cfg, h)
+    # the decoder's self-attention stays on XLA: the flash kernel has not
+    # been compiled or measured inside the encoder-decoder step
+    h = attention.apply(p_l["self_attn"], cfg, h, flash=False)
     x = x + h
     h = norms.apply(p_l["ln2"], x, cfg.norm_eps)
     x = x + _cross_attend(p_l["cross_attn"], cfg, h,

@@ -4,7 +4,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops
 from repro.models.layers import attention_core as core
 from repro.models.layers.rope import apply_rope
@@ -70,22 +72,50 @@ def _project_qkv(params, cfg: ModelConfig, x, positions):
     return q, k, v
 
 
+def _kernel_path(cfg: ModelConfig, q, prefix_len: int, flash: bool) -> bool:
+    """Whether training attention runs the flash kernel: the caller allows
+    it, the kernels are on, the mask is plain causal (no prefix, no
+    softcap) and the kernel's blocks tile the sequence within its VMEM
+    budget."""
+    _, s, _, d = q.shape
+    return (flash and ops.use_kernels(cfg) and not prefix_len
+            and not cfg.attn_logit_softcap
+            and fa.fits(s, d, q.dtype.itemsize))
+
+
 def apply(params: dict, cfg: ModelConfig, x: jax.Array, *, positions=None,
-          prefix_len: int = 0, chunk_q: int = 512,
-          segment_ids=None) -> jax.Array:
+          prefix_len: int = 0, chunk_q: int = 512, segment_ids=None,
+          mesh=None, batch_axes=("data",), flash: bool = True) -> jax.Array:
     """Training/prefill forward (causal). x: [B, S, D] -> [B, S, D].
 
     ``positions``: [S] or [B, S] RoPE positions (packed batches pass
     per-segment-reset positions). ``segment_ids``: [B, S] packed segment
-    ids (0 = pad) — attention is block-diagonal over equal segments."""
+    ids (0 = pad) — attention is block-diagonal over equal segments.
+    Plain causal attention runs the flash kernel where ``_kernel_path``
+    allows it (per shard of ``mesh``), everything else
+    ``chunked_attention``; ``flash=False`` keeps a caller on the latter
+    (the encoder-decoder's decoder). The counters
+    ``attention/kernel_layers`` and ``attention/xla_layers`` count the
+    layers traced on each path."""
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)
     q, k, v = _project_qkv(params, cfg, x, positions)
-    out = core.chunked_attention(q, k, v, hmap=_hmap(cfg), chunk_q=chunk_q,
-                                 causal=True, prefix_len=prefix_len,
-                                 softcap=cfg.attn_logit_softcap,
-                                 segment_ids=segment_ids)
+    kernel = _kernel_path(cfg, q, prefix_len, flash)
+    obs.metrics.counter("kernel_layers" if kernel else "xla_layers",
+                        subsystem="attention").inc()
+    if kernel:
+        hmap = _hmap(cfg)
+        out = ops.flash_attention(q, core.expand_kv(k, hmap),
+                                  core.expand_kv(v, hmap), causal=True,
+                                  segment_ids=segment_ids, mesh=mesh,
+                                  batch_axes=batch_axes)
+    else:
+        out = core.chunked_attention(q, k, v, hmap=_hmap(cfg),
+                                     chunk_q=chunk_q, causal=True,
+                                     prefix_len=prefix_len,
+                                     softcap=cfg.attn_logit_softcap,
+                                     segment_ids=segment_ids)
     out = out.astype(x.dtype)
     hm = _head_mask(cfg, out.dtype)
     if hm is not None:

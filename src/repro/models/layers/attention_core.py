@@ -9,14 +9,19 @@ FLOP count is identical to grouped GQA.
 
 Execution paths:
   * ``chunked_attention``  -- q-chunked exact attention via lax.scan; the XLA
-    path used for training/prefill (bounds the score-matrix working set to
-    [B, H, chunk_q, S_k]).
+    path for every attention the flash kernel does not take (bounds the
+    score-matrix working set to [B, H, chunk_q, S_k]): serving prefill,
+    prefix-LM (vlm) and softcapped masks, MLA, the encoder-decoder's
+    encoder and decoder, sequences the kernel's blocks do not tile, and
+    everything with the kernels off.
   * ``decode_attention``   -- single-query attention against a length-masked
     KV cache.
-  * No layer calls the Pallas flash kernels (kernels/flash_attention.py):
-    they, the dense Pallas decode kernel and the RMSNorm kernel are reached
-    only from tests/test_kernels.py. The attention kernel on a main path is
-    the paged decode kernel, called by layers/attention.apply_decode_paged.
+  * Training attention in ``layers/attention.apply`` runs the Pallas flash
+    kernel (kernels/flash_attention.py) when the kernels are on and the
+    mask is plain causal, packed segments included. The other attention
+    kernel on a main path is the paged decode kernel, called by
+    layers/attention.apply_decode_paged; the dense Pallas decode kernel and
+    the RMSNorm kernel are reached only from tests/test_kernels.py.
 """
 from __future__ import annotations
 

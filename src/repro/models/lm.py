@@ -197,13 +197,14 @@ def apply_train(params: dict, cfg: ModelConfig, batch: dict, *, mesh=None,
 
     aux = jnp.zeros((), jnp.float32)
     if cfg.family in ("dense", "vlm"):
-        fn = partial(_apply_attn_block, cfg, prefix_len, positions,
-                     segment_ids)
+        fn = partial(_apply_attn_block, cfg, mesh, batch_axes, prefix_len,
+                     positions, segment_ids)
         x, a = scan_stack(cfg, fn, x, params["layers"], masks.get("layers"))
         aux += a
     elif cfg.family == "moe":
         if cfg.first_k_dense:
-            fn = partial(_apply_attn_block, cfg, 0, positions, segment_ids)
+            fn = partial(_apply_attn_block, cfg, mesh, batch_axes, 0,
+                         positions, segment_ids)
             x, a = scan_stack(cfg, fn, x, params["dense_layers"],
                               masks.get("dense_layers"))
             aux += a
@@ -216,7 +217,7 @@ def apply_train(params: dict, cfg: ModelConfig, batch: dict, *, mesh=None,
         x, a = scan_stack(cfg, fn, x, params["layers"], masks.get("layers"))
         aux += a
     elif cfg.family == "hybrid":
-        x, a = _hybrid_train(params, cfg, x, masks)
+        x, a = _hybrid_train(params, cfg, x, masks, mesh, batch_axes)
         aux += a
 
     h_pre = x
@@ -232,10 +233,12 @@ def apply_train(params: dict, cfg: ModelConfig, batch: dict, *, mesh=None,
     return logits, aux, extra
 
 
-def _apply_attn_block(cfg, prefix_len, positions, segment_ids, p_l, x):
+def _apply_attn_block(cfg, mesh, batch_axes, prefix_len, positions,
+                      segment_ids, p_l, x):
     return blocks.attn_block_apply(p_l, cfg, x, prefix_len=prefix_len,
                                    positions=positions,
-                                   segment_ids=segment_ids)
+                                   segment_ids=segment_ids, mesh=mesh,
+                                   batch_axes=batch_axes)
 
 
 def _apply_moe_block(cfg, mesh, batch_axes, positions, segment_ids, p_l, x):
@@ -248,7 +251,7 @@ def _apply_ssm_block(cfg, p_l, x):
     return blocks.ssm_block_apply(p_l, cfg, x)
 
 
-def _hybrid_train(params, cfg: ModelConfig, x, masks):
+def _hybrid_train(params, cfg: ModelConfig, x, masks, mesh, batch_axes):
     """ssm layers with the shared attn block applied every period layers.
     Shared-block weight sharing = same params closed over at every site."""
     p, nsite, rem = _hybrid_split(cfg)
@@ -266,7 +269,8 @@ def _hybrid_train(params, cfg: ModelConfig, x, masks):
         grp, gm = xs if gmask is not None else (xs, None)
         x, a = scan_stack(cfg, partial(_apply_ssm_block, cfg), x, grp, gm)
         aux += a
-        shared_fn = lambda p_l, xx: blocks.attn_block_apply(p_l, cfg, xx)  # noqa: E731
+        shared_fn = lambda p_l, xx: blocks.attn_block_apply(  # noqa: E731
+            p_l, cfg, xx, mesh=mesh, batch_axes=batch_axes)
         if cfg.gate_weight_grads and smask is not None:
             x, a2 = _gated(shared_fn, shared, x, smask)
         else:
@@ -296,7 +300,8 @@ def _mtp_logits(params, cfg: ModelConfig, h_pre, tokens, mesh, batch_axes):
         h, _ = blocks.moe_block_apply(m["block"], cfg, h, mesh=mesh,
                                       batch_axes=batch_axes)
     else:
-        h, _ = blocks.attn_block_apply(m["block"], cfg, h)
+        h, _ = blocks.attn_block_apply(m["block"], cfg, h, mesh=mesh,
+                                       batch_axes=batch_axes)
     h = norms.apply(params["final_norm"], h, cfg.norm_eps)
     return _logits(params, cfg, h)
 

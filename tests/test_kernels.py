@@ -5,7 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.configs.base import ModelConfig
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
+from repro.models.layers import attention
+from repro.models.layers import attention_core as core
 
 
 def _tol(dtype):
@@ -141,6 +146,175 @@ class TestFlashAttention:
         for a, b_ in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=1e-4, atol=1e-4)
+
+
+    @staticmethod
+    def _skipping_segments():
+        """[2, 512] packed rows whose later blocks hold no key of the first
+        segment: one full row, one with a pad tail."""
+        return np.array([[1] * 200 + [2] * 250 + [3] * 62,
+                         [1] * 130 + [2] * 300 + [0] * 82], np.int32)
+
+    def test_loop_ranges_skip_other_segments(self):
+        """Per Q block, the K blocks the loops visit: causal, and from the
+        first block whose ids meet the Q block's; per K block, the Q blocks
+        that attend to it."""
+        (first, end), (kfirst, kend) = fa._ranges(
+            jnp.asarray(self._skipping_segments()), 512, 128, 128, True)
+        # row 0: Q blocks 0..3 hold ids {1}, {1, 2}, {2}, {2, 3}
+        assert first.tolist()[:4] == [0, 0, 1, 1]
+        assert end.tolist()[:4] == [1, 2, 3, 4]
+        # row 1: the pad tail (id 0) meets every block up to the diagonal
+        assert first.tolist()[4:] == [0, 0, 1, 0]
+        # K block 0 of row 0 (id 1) is attended by Q blocks 0 and 1 only
+        assert (kfirst.tolist()[0], kend.tolist()[0]) == (0, 2)
+        # K block 2 of row 1 (ids 2 and pad) is attended by Q blocks 2, 3
+        assert (kfirst.tolist()[6], kend.tolist()[6]) == (2, 4)
+
+    @pytest.mark.parametrize("segs", ["none", "packed", "repeated"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("d", [64, 128])
+    def test_gqa_expanded_matches_ref(self, d, dtype, segs):
+        """The layer's use: 4 query heads over 2 kv heads gather-expanded
+        before the kernel, packed segments or none; output and gradients
+        (through the gather, onto the kv heads) against the f32 oracle.
+        ``repeated``: an id that comes back after another segment, so the
+        blocks skipped between its runs must hold no pair of it."""
+        b, s, h, kvh = 2, 512, 4, 2
+        ks = jax.random.split(jax.random.PRNGKey(5), 4)
+        q = (0.5 * jax.random.normal(ks[0], (b, s, h, d))).astype(dtype)
+        k, v = ((0.5 * jax.random.normal(kk, (b, s, kvh, d))).astype(dtype)
+                for kk in ks[1:3])
+        ct = jax.random.normal(ks[3], (b, s, h, d))
+        seg = None
+        if segs == "packed":
+            seg = jnp.asarray(self._skipping_segments(), jnp.int32)
+        elif segs == "repeated":
+            seg = jnp.asarray(np.repeat([[1, 2, 1, 3], [2, 1, 2, 2]],
+                                        s // 4, axis=1), jnp.int32)
+        hmap = core.head2group(h, kvh)
+        fold = lambda t: t.transpose(0, 2, 1, 3)  # noqa: E731
+
+        def lk(q, k, v):
+            o = ops.flash_attention(q, core.expand_kv(k, hmap),
+                                    core.expand_kv(v, hmap), segment_ids=seg)
+            return jnp.sum(o.astype(jnp.float32) * ct), o
+
+        def lr(q, k, v):
+            o = ref.flash_attention(fold(q), fold(core.expand_kv(k, hmap)),
+                                    fold(core.expand_kv(v, hmap)),
+                                    segment_ids=seg).transpose(0, 2, 1, 3)
+            return jnp.sum(o.astype(jnp.float32) * ct), o
+
+        (_, ok), gk = jax.value_and_grad(lk, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+        (_, orf), gr = jax.value_and_grad(lr, argnums=(0, 1, 2),
+                                          has_aux=True)(q, k, v)
+        np.testing.assert_allclose(np.asarray(ok, np.float32),
+                                   np.asarray(orf, np.float32), **_tol(dtype))
+        bound = 1e-2 if dtype == jnp.bfloat16 else 1e-5
+        for a, b_ in zip(gk, gr):
+            a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+            assert np.linalg.norm(a - b_) <= bound * np.linalg.norm(b_)
+
+
+def _counts():
+    got = obs.metrics.snapshot().get("attention", {})
+    return got.get("kernel_layers", 0), got.get("xla_layers", 0)
+
+
+class TestAttentionKernelPath:
+    """``attention.apply`` runs the flash kernel for plain causal training
+    attention and ``chunked_attention`` for everything else."""
+
+    CFG = ModelConfig(name="flash-layer", family="dense", num_layers=1,
+                      d_model=128, num_heads=14, num_kv_heads=2, head_dim=64,
+                      pad_heads_to=16, d_ff=256, vocab_size=32,
+                      attn_bias=True, dtype="bfloat16")
+
+    def _inputs(self, s=256):
+        b = 2
+        seg = np.zeros((b, s), np.int32)
+        pos = np.zeros((b, s), np.int32)
+        for row, cuts in enumerate(([0, 90, s - 56, s - 24], [0, 150, s])):
+            for i, (lo, hi) in enumerate(zip(cuts[:-1], cuts[1:])):
+                seg[row, lo:hi] = i + 1
+                pos[row, lo:hi] = np.arange(hi - lo)
+        params = attention.init(jax.random.PRNGKey(0), self.CFG)
+        x = jax.random.normal(jax.random.PRNGKey(1), (b, s, 128),
+                              jnp.bfloat16)
+        return params, x, jnp.asarray(pos), jnp.asarray(seg)
+
+    def test_kernel_path_matches_xla_path(self):
+        params, x, pos, seg = self._inputs()
+        ct = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+        runs = {}
+        for mode in ("always", "never"):
+            cfg = self.CFG.replace(use_pallas=mode)
+
+            def loss(params, x):
+                out = attention.apply(params, cfg, x, positions=pos,
+                                      segment_ids=seg)
+                return jnp.sum(out.astype(jnp.float32) * ct), out
+
+            before = _counts()
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True)(params, x)
+            after = _counts()
+            runs[mode] = (out, grads, (after[0] - before[0],
+                                       after[1] - before[1]))
+        assert runs["always"][2] == (1, 0)
+        assert runs["never"][2] == (0, 1)
+        np.testing.assert_allclose(np.asarray(runs["always"][0], np.float32),
+                                   np.asarray(runs["never"][0], np.float32),
+                                   **_tol(jnp.bfloat16))
+        for a, b_ in zip(jax.tree.leaves(runs["always"][1]),
+                         jax.tree.leaves(runs["never"][1])):
+            a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+            assert np.linalg.norm(a - b_) <= 2e-2 * np.linalg.norm(b_)
+        # the padded heads (14 -> 16) stay zero-masked: no gradient
+        gq = np.asarray(runs["always"][1][0]["wq"], np.float32)
+        assert not gq[:, 14:].any()
+
+    @pytest.mark.parametrize("case", ["prefix", "softcap", "untiled"])
+    def test_other_masks_take_the_xla_path(self, case):
+        params, x, pos, seg = self._inputs(s=200 if case == "untiled"
+                                           else 256)
+        cfg = self.CFG.replace(use_pallas="always")
+        kw = {}
+        if case == "prefix":
+            kw = {"prefix_len": 16}
+        elif case == "softcap":
+            cfg = cfg.replace(attn_logit_softcap=30.0)
+        before = _counts()
+        out = attention.apply(params, cfg, x, **kw)
+        after = _counts()
+        assert (after[0] - before[0], after[1] - before[1]) == (0, 1)
+        want = attention.apply(params, cfg.replace(use_pallas="never"), x,
+                               **kw)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(want, np.float32))
+
+    def test_encdec_decoder_takes_the_xla_path(self):
+        """The encoder-decoder's decoder self-attention is plain causal, but
+        keeps ``chunked_attention`` with the kernels on."""
+        from repro.configs import get_smoke_config
+        from repro.models import encdec
+        cfg = get_smoke_config("seamless-m4t-medium").replace(
+            use_pallas="always")
+        p_l = encdec.dec_block_init(jax.random.PRNGKey(0), cfg)
+        x = jax.random.normal(jax.random.PRNGKey(1), (2, 256, cfg.d_model),
+                              jnp.dtype(cfg.dtype))
+        enc_out = jax.random.normal(jax.random.PRNGKey(2),
+                                    (2, 64, cfg.d_model), x.dtype)
+        before = _counts()
+        out, _ = encdec.dec_block_apply(p_l, cfg, x, enc_out)
+        after = _counts()
+        assert (after[0] - before[0], after[1] - before[1]) == (0, 1)
+        want, _ = encdec.dec_block_apply(
+            p_l, cfg.replace(use_pallas="never"), x, enc_out)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 class TestDecodeAttention:

@@ -17,10 +17,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels import block_grad_norm, decode_attention, masked_adamw, ops
+from repro.kernels import (block_grad_norm, decode_attention, flash_attention,
+                           masked_adamw, ops)
 from repro.models.layers.attention import _hmap
 
 QWEN = get_config("qwen2.5-0.5b")
@@ -38,7 +40,7 @@ LEAVES = {
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -52,9 +54,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -111,3 +118,64 @@ def test_paged_decode_attention_compiles(one_chip):
     pool = ((num_pages, kvh, page_size, d), jnp.bfloat16)
     _compile(fn, one_chip, ((slots, h, d), jnp.bfloat16), pool, pool,
              ((slots, maxp), jnp.int32), ((slots,), jnp.int32))
+
+
+def test_flash_attention_segmented_fwd_bwd_compiles(one_chip):
+    """Training attention of the qwen2.5-0.5b cells: 8 packed rows of 1024,
+    16 (padded) heads of 64, int32 segment ids; forward and backward."""
+    b, s, h, d = 8, 1024, QWEN.padded_heads, QWEN.head_dim
+    bq, bk = flash_attention.block_sizes(s, d)
+
+    def fn(q, k, v, seg):
+        def loss(q, k, v):
+            o = flash_attention.flash_attention(q, k, v, seg, h, bq, bk,
+                                                True, False)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    qkv = ((b * h, s, d), jnp.bfloat16)
+    _compile(fn, one_chip, qkv, qkv, qkv, ((b, s), jnp.int32))
+
+
+# (S, D, segmented): every head width of a registered config that reaches
+# the kernel (64, 112, 128, 256), both block sizes (256 where they tile S,
+# else 128), and the plain causal kernel
+FLASH_SHAPES = [(1024, 128, True), (1024, 112, True), (2048, 256, True),
+                (384, 64, True), (1024, 128, False)]
+
+
+@pytest.mark.parametrize("s,d,segmented", FLASH_SHAPES)
+def test_flash_attention_widths_compile(one_chip, s, d, segmented):
+    b, h = 2, 4
+    assert flash_attention.fits(s, d, 2)
+    bq, bk = flash_attention.block_sizes(s, d)
+
+    def fn(q, k, v, seg):
+        def loss(q, k, v):
+            o = flash_attention.flash_attention(
+                q, k, v, seg if segmented else None, h, bq, bk, True, False)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    qkv = ((b * h, s, d), jnp.bfloat16)
+    _compile(fn, one_chip, qkv, qkv, qkv, ((b, s), jnp.int32))
+
+
+def test_flash_attention_per_shard_compiles(topology, monkeypatch):
+    """The layer's call on a 2 x 2 (data, model) mesh of described chips:
+    each chip runs the kernel on its shard (batch over data, heads over
+    model) inside ``shard_map``, forward and backward."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topology.devices[:4]).reshape(2, 2),
+                ("data", "model"))
+    b, s, h, d = 8, 1024, QWEN.padded_heads, QWEN.head_dim
+
+    def fn(q, k, v, seg):
+        def loss(q, k, v):
+            o = ops.flash_attention(q, k, v, segment_ids=seg, mesh=mesh)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    act = NamedSharding(mesh, P("data", None, "model", None))
+    qkv = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=act)
+    seg = jax.ShapeDtypeStruct((b, s), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data", None)))
+    hlo = jax.jit(fn).lower(qkv, qkv, qkv, seg).compile().as_text()
+    assert "tpu_custom_call" in hlo, "the kernel did not lower to Mosaic"
