@@ -5,13 +5,18 @@ A cell is one entry of ``workloads`` in ``BENCHMARK.json``. Its files are
 found by name, so a later change adds a cell, a traffic mix, a model
 configuration or a per-layer metric by adding a file:
 
-    bench/workloads/<cell>.json     system settings and correctness limits
+    bench/workloads/<cell>.json     system settings and correctness limits,
+                                    and the data-parallel degree
     bench/traffic/<traffic>.json    the mix or job, read by bench/traffic.py
-    bench/configs/<config>.json     published sizes, as run
+    bench/configs/<config>.json     published sizes, as run; its
+                                    ``"reference"`` names the family module
+    bench/families/<family>.py      all that is architecture-specific (see
+                                    bench/families/dense_decoder.py)
     bench/metrics/<metric>.py       ``read(ctx) -> float | None``
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import math
@@ -40,6 +45,12 @@ class Cell:
     @property
     def kind(self) -> str:
         return self.traffic["kind"]
+
+    @functools.cached_property
+    def family(self):
+        """The configuration's family module, loaded on first use (after
+        the compile cache is placed: it imports JAX)."""
+        return load_module(self.root / self.config["reference"])
 
 
 def _read_json(path: Path) -> dict:
@@ -71,14 +82,20 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         end_to_end=e2e, per_layer=per_layer)
 
 
-def metric_reader(name: str, root: Path = ROOT):
-    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+def load_module(path: Path):
+    """The Python module at ``path``, loaded under a name of its own."""
+    path = Path(path)
+    name = "bench_" + "_".join(path.with_suffix("").parts[-2:]).replace(
+        ".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
+    return load_module(root / "bench" / "metrics" / f"{name}.py").read
 
 
 def read_per_layer(cell: Cell, ctx: dict) -> dict:
@@ -116,15 +133,14 @@ def require_chips(need: int) -> list:
 
 
 def device_report(devs) -> dict:
-    """Platform, kind and count as JAX reports them, and the peak bytes in
-    use on the fullest chip."""
-    import jax
+    """Platform and kind as JAX reports them, the count of the cell's
+    chips, and the peak bytes in use on the fullest of them."""
     peak = 0
     for d in devs:
         stats = d.memory_stats() or {}
         peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(jax.devices()), "memory_peak_bytes": peak}
+            "count": len(devs), "memory_peak_bytes": peak}
 
 
 def peaks(device_kind: str, root: Path = ROOT) -> dict:
@@ -134,22 +150,6 @@ def peaks(device_kind: str, root: Path = ROOT) -> dict:
         raise KeyError(f"bench/peaks.json has no peaks for device kind "
                        f"{device_kind!r}")
     return table["devices"][device_kind]
-
-
-class CompileCount:
-    """Counts, while ``on``, the programs the process had to compile or
-    load from the persistent cache: every jit cache miss passes through
-    one. The measured window should count none."""
-
-    def __init__(self):
-        import jax
-        self.on = False
-        self.requests = 0
-        jax.monitoring.register_event_listener(self._event)
-
-    def _event(self, name: str, *_args, **_kw):
-        if self.on and name == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
 
 
 class Trace:

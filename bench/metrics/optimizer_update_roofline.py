@@ -1,7 +1,9 @@
 """The masked AdamW update's share of its roofline: the least bytes of the
 update of the blocks each step selected (read p, g, m, v; write p, m, v)
 over peak HBM bandwidth, against the device time of the update kernel's
-events (dense ``masked_adamw`` or ``banked_masked_adamw``)."""
+events (dense ``masked_adamw`` or ``banked_masked_adamw``) on every chip
+of the cell (the trace's kernel time is per chip): an update that each
+chip repeats counts once."""
 
 KERNELS = ("masked_adamw",)
 
@@ -14,4 +16,4 @@ def read(ctx):
     if t <= 0 or not ctx["optimizer_update_bytes"]:
         return None
     least = ctx["optimizer_update_bytes"] / ctx["peaks"]["hbm_bytes_per_s"]
-    return 100.0 * least / t
+    return 100.0 * least / (t * ctx["chips"])

@@ -39,16 +39,20 @@ def parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None, root: Path = harness.ROOT, devices=None) -> int:
-    """``devices``: the chips to run on; by default the cell's count of
-    TPU chips, or an exit without a result."""
+    """``devices``: the devices to take the cell's chips from; by default
+    the machine's TPU chips, or an exit without a result."""
     args = parser().parse_args(argv)
     if args.seed < 0:
         sys.exit("bench: --seed must be a non-negative integer")
     cell = harness.load_cell(args.workload, root)
-    devs = devices
-    if devs is None:
+    if devices is None:
         harness.place_compile_cache(root)
         devs = harness.require_chips(cell.chips)
+    elif len(devices) < cell.chips:
+        sys.exit(f"bench: the cell needs {cell.chips} chips, found "
+                 f"{len(devices)}")
+    else:
+        devs = list(devices)[:cell.chips]
     if cell.kind != "train":
         sys.exit(f"bench: unknown traffic kind {cell.kind!r}")
     import train as driver

@@ -116,20 +116,22 @@ def test_a_lost_restore_is_not_correct(tmp_path, capsys):
 
 
 def test_the_checked_steps_evict_and_restore(tmp_path, capsys):
-    """Every seed's checked steps explore, exploit, explore: a block leaves
-    the bank and comes back, and the window draws as many exploit steps."""
+    """The banked cell fixes the program's selection seed, so every seed
+    makes the same selections: its checked steps explore, exploit,
+    explore (a block leaves the bank and comes back), and its window
+    exploits 32 of its 89 steps."""
     import train
-    cell = harness.load_cell(tiny.CELL, tiny.make_root(tmp_path))
-    draws = cell.workload["selection_draws"]
-    for seed in (0, 2 ** 31 + 5, 2 ** 40 + 3):
-        s = train.program_seed(cell, seed)
-        assert 0 <= s < 2 ** 31
-        ex = train._explores(np.array([s]), cell.traffic["job"]["select"],
-                             3 + draws["window_steps"])[0]
-        assert ex[0] and not ex[1] and ex[2]
-        assert np.sum(~ex[3:]) == draws["window_exploits"]
-    assert train.program_seed(cell, 7) == train.program_seed(cell, 7)
-    assert train.program_seed(cell, 7) != train.program_seed(cell, 8)
+    cell = harness.load_cell("qwen2.5-0.5b.train.ags_banked")
+    chosen = cell.workload["selection_seed"]
+    assert 0 <= chosen < 2 ** 31
+    assert {train.program_seed(cell, s)
+            for s in (0, 7, 2 ** 31 + 5, 2 ** 40 + 3)} == {chosen}
+    bench = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+    steps = round(bench["run_seconds"] / cell.workload["step_s"])
+    ex = train._explores(np.array([chosen]), cell.traffic["job"]["select"],
+                         train.CHECK_STEPS + steps)[0]
+    assert ex[0] and not ex[1] and ex[2]
+    assert (steps, int(np.sum(~ex[train.CHECK_STEPS:]))) == (89, 32)
     line = _run(tiny.make_root(tmp_path / "run"), capsys)
     assert line["checks"]["blocks_restored"]["value"] >= 1, line["checks"]
 

@@ -11,11 +11,14 @@ CELL = "tiny.train"
 
 
 def make_root(root: Path, *, method: str = "adagradselect",
-              residency: str = "banked") -> Path:
-    """The tiny cell ``tiny.train``."""
+              residency: str = "banked", chips: int = 1) -> Path:
+    """The tiny cell ``tiny.train``; on more than one chip, data-parallel
+    over them with its banked store sharded over them (``zero1``)."""
     bench = root / "bench"
     for d in ("traffic", "workloads", "configs", "metrics"):
         (bench / d).mkdir(parents=True, exist_ok=True)
+    shutil.copytree(BENCH / "families", bench / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     peaks = json.loads((BENCH / "peaks.json").read_text())
     peaks["devices"]["cpu"] = peaks["devices"]["TPU v5 lite"]
     (bench / "peaks.json").write_text(json.dumps(peaks))
@@ -37,8 +40,11 @@ def make_root(root: Path, *, method: str = "adagradselect",
                      / "qwen2.5-0.5b.train.ags_banked.json").read_text())
     # the tiny model runs at float32, where the program reads about 1e-7
     # (losses, gradients) and 2e-4 (changes) off the reference on the CPU
-    wl.update({"moment_residency": residency,
-               "offload": "host" if residency == "banked" else "none",
+    offload = "host" if chips == 1 else "zero1"
+    # a selection seed whose checked steps explore, exploit, explore and
+    # whose first window step exploits, so a short window moves blocks too
+    wl.update({"moment_residency": residency, "selection_seed": 14299,
+               "offload": offload if residency == "banked" else "none",
                "limits": {"loss_gap": 1e-4, "grad_gap": 1e-3,
                           "change_gap": 1e-2}})
     (bench / "workloads" / f"{CELL}.json").write_text(json.dumps(wl))
@@ -46,7 +52,7 @@ def make_root(root: Path, *, method: str = "adagradselect",
     b["configs"] = [{"name": "tiny", "source": "test", "reduced": [],
                      "file": "bench/configs/tiny.json", "why": "test"}]
     b["workloads"] = [{"name": CELL, "config": "tiny", "traffic": "tiny",
-                       "chips": 1, "why": "test"}]
+                       "chips": chips, "why": "test"}]
     for m in b["end_to_end"] + b["per_layer"]:
         if "workloads" in m:
             m["workloads"] = [CELL]

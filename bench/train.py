@@ -14,7 +14,6 @@ runs the first steps again.
 from __future__ import annotations
 
 import contextlib
-import functools
 import gc
 import sys
 import time
@@ -22,14 +21,16 @@ import time
 import numpy as np
 
 import harness
-import program
 import traffic
 import weights
 import work
 
 CHECK_STEPS = 3
-# host spans (repro.obs) that label the device's idle gaps
-SPAN_LABELS = ("phase_a", "swap", "phase_b", "log_sync", "train_step")
+# host spans (repro.obs) that label the device's idle gaps; a gap takes the
+# innermost span open at its midpoint
+SPAN_LABELS = ("phase_a", "swap", "phase_b", "log_sync", "train_step",
+               "index_sync", "swap_join", "swap_commit", "swap_sync",
+               "data_wait")
 
 
 def _say(msg: str) -> None:
@@ -55,16 +56,13 @@ def train_config(cell, seed: int):
                           moment_residency=sysw["moment_residency"],
                           offload=sysw["offload"],
                           async_swap=sysw["async_swap"])
-    return TrainConfig(model=program.program_config(cell.config),
+    return TrainConfig(model=cell.family.program_config(cell.config),
                        method=job["method"],
                        select=SelectConfig(**job["select"]), optimizer=opt,
                        seq_len=job["seq_len"],
                        global_batch=job["global_batch"],
                        steps=job["optimizer"]["total_steps"],
                        seed=program_seed(cell, seed))
-
-
-CANDIDATES = 1 << 16
 
 
 def _explores(cands: np.ndarray, sel: dict, steps: int) -> np.ndarray:
@@ -92,35 +90,18 @@ def _explores(cands: np.ndarray, sel: dict, steps: int) -> np.ndarray:
     return np.asarray(draws(jnp.asarray(cands, jnp.int32)))
 
 
-@functools.lru_cache(maxsize=None)
-def _selection_seed(seed: int, sel: tuple, steps: int, exploits: int) -> int:
-    sel = dict(sel)
-    rng = np.random.default_rng(np.random.SeedSequence(int(seed)))
-    cands = rng.integers(0, 2 ** 31, CANDIDATES, dtype=np.int64)
-    ex = _explores(cands, sel, CHECK_STEPS + steps)
-    ok = (ex[:, 0] & ~ex[:, 1] & ex[:, 2]
-          & (np.sum(~ex[:, CHECK_STEPS:], axis=1) == exploits))
-    if not ok.any():
-        raise RuntimeError(f"bench: no selection seed among {CANDIDATES} "
-                           f"candidates of seed {seed}")
-    return int(cands[np.argmax(ok)])
-
-
 def program_seed(cell, seed: int) -> int:
     """The seed handed to the program (its PRNGKey takes 31 bits).
 
-    For AdaGradSelect it is the first of a list of candidates drawn from
-    ``seed`` under which the checked steps explore, exploit, explore (step
-    1 evicts blocks that step 2 brings back from the store) and the
-    window's first ``window_steps`` steps exploit exactly
-    ``window_exploits`` times: every seed does as much boundary work."""
-    job = cell.traffic["job"]
-    if job["method"] != "adagradselect":
+    For AdaGradSelect it is the workload's ``selection_seed``: every seed
+    then makes the same selections, so the boundary moves the same blocks
+    and the window does the same work; ``seed`` varies the weights and the
+    rows. The workload's seed is one under which the checked steps explore,
+    exploit, explore (step 1 evicts blocks that step 2 brings back from
+    the store)."""
+    if cell.traffic["job"]["method"] != "adagradselect":
         return int(seed) % (2 ** 31)
-    draws = cell.workload["selection_draws"]
-    return _selection_seed(int(seed), tuple(sorted(job["select"].items())),
-                           int(draws["window_steps"]),
-                           int(draws["window_exploits"]))
+    return int(cell.workload["selection_seed"])
 
 
 def make_feed(cell, seed: int):
@@ -143,7 +124,19 @@ def first_batches(feed) -> list:
     return out
 
 
-def build(cell, seed: int):
+def data_mesh(cell, devs):
+    """The program's data-parallel mesh over the cell's chips:
+    ``("data", "model")`` with a model axis of 1, as its launcher's
+    ``--mesh data`` lays it out. None on one chip, where the trainer runs
+    without a mesh."""
+    if cell.chips == 1:
+        return None
+    from jax.sharding import AxisType, Mesh
+    grid = np.array(devs[:cell.chips]).reshape(cell.chips, 1)
+    return Mesh(grid, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+
+
+def build(cell, seed: int, devs=None):
     """The trainer with the seeded weights in its state, and its feed."""
     import jax
 
@@ -152,17 +145,19 @@ def build(cell, seed: int):
 
     tcfg = train_config(cell, seed)
     mcfg = tcfg.model
-    s = work.sizes(cell.config)
     feed = make_feed(cell, seed)
+    mesh = data_mesh(cell, devs)
     trainer = Trainer(tcfg, data_source=feed,
-                      prefetch_depth=cell.workload["prefetch_depth"])
+                      prefetch_depth=cell.workload["prefetch_depth"],
+                      **({"mesh": mesh} if mesh is not None else {}))
     # the program's own random parameters leave before the seeded ones
     # arrive, so the two never share the device (set-up would otherwise
     # set the peak memory)
     trainer.state["params"] = None
-    params = weights.program_tree(seed, s, program.has_bias(cell.config),
-                                  mcfg.padded_heads, mcfg.padded_vocab_size,
-                                  mcfg.dtype)
+    shardings = (None if trainer.state_shardings is None
+                 else trainer.state_shardings["params"])
+    params = weights.program_tree(seed, cell.family.leaves(cell.config),
+                                  mcfg.dtype, shardings)
     weights.check_against(params, jax.eval_shape(
         lambda: registry.get(mcfg).init(jax.random.PRNGKey(0), mcfg)))
     trainer.state["params"] = params
@@ -183,50 +178,93 @@ def first_grad_norms(trainer, cell) -> dict:
     optimizer got it: its first moment after one step over (1 - b1)."""
     import jax
     import jax.numpy as jnp
+
+    from repro.core import partition as part_mod
     b1 = trainer.tcfg.optimizer.b1
-    nl = trainer.tcfg.model.num_layers
+    groups = {g.key: g for g in
+              part_mod.build_partition(trainer.tcfg.model).groups}
     opt = trainer.state["opt"]
     norm = jax.jit(_rows_norm)
     out = {}
-    for name, (path, _, _) in weights.PROGRAM_PATH.items():
-        if name.startswith("b") and not program.has_bias(cell.config):
-            continue
+    for lf in cell.family.leaves(cell.config):
         if "m" in opt:                                  # dense moments
-            leaf = weights.at_path(opt["m"], path)
+            leaf = weights.at_path(opt["m"], lf.path)
             rows = np.ones((leaf.shape[0],), bool)
         else:                                           # banked moments:
-            bank = opt["banks"][path[0]]                # rows of resident
-            leaf = weights.at_path(bank["m"], path[1:])  # blocks only
+            group = groups[lf.path[0]]                  # rows of resident
+            bank = opt["banks"][group.key]              # blocks only
+            leaf = weights.at_path(bank["m"], lf.path[1:])
             slots = np.asarray(bank["slots"])
-            if path[0] == "layers":
-                rows = slots < nl
+            if group.stacked:
+                rows = slots < group.length
             else:
-                rows = np.full((leaf.shape[0],), bool(slots[0] < 1))
-        out[name] = float(norm(leaf, jnp.asarray(rows))) / (1 - b1)
+                rows = np.full((leaf.shape[0],),
+                               bool(slots[0] < group.length))
+        out[lf.name] = float(norm(leaf, jnp.asarray(rows))) / (1 - b1)
     return out
 
 
 def _warm_swap_shapes(trainer) -> None:
-    """Compile the banked boundary's row gather and scatter of the stacked
-    layers for every row count the window can ask for, so that nothing
-    compiles inside it."""
+    """Compile the banked boundary's row moves for every row count the
+    window can ask for, so that nothing compiles inside it: the gather and
+    scatter of each stacked group's bank rows; where the store is on the
+    device (``zero1``), the boundary's own store reads and writes of every
+    group too, on copies of what it would donate."""
     import jax
     import jax.numpy as jnp
 
     from repro.core import masked_adamw as ma
-    bank = trainer.state["opt"].get("banks", {}).get("layers")
+    from repro.core import partition as part_mod
+    opt = trainer.state["opt"]
     gather = getattr(ma, "_gather_group", None)
     scatter = getattr(ma, "_scatter_group_donated", None)
-    if bank is None or gather is None or scatter is None:
+    if "banks" not in opt or gather is None or scatter is None:
         return
-    leaves = tuple(jax.tree.leaves(bank["m"]) + jax.tree.leaves(bank["v"]))
-    for n in range(1, int(bank["slots"].shape[0]) + 1):
-        # the boundary turns numpy slot lists into device indices
-        jnp.asarray(np.zeros((n,), np.int64), jnp.int32)
-        idx = jnp.zeros((n,), jnp.int32)
-        rows = gather(leaves, idx)
-        jax.block_until_ready(scatter(tuple(jnp.copy(x) for x in leaves),
-                                      idx, rows))
+    for g in part_mod.build_partition(trainer.tcfg.model).groups:
+        bank = opt["banks"][g.key]
+        cap = int(bank["slots"].shape[0])
+        if not isinstance(jax.tree.leaves(opt["store"][g.key])[0],
+                          np.ndarray):
+            for n in range(1, cap + 1):
+                _warm_device_store(ma, g, n, bank, opt["store"])
+            continue
+        if not g.stacked:
+            continue
+        leaves = tuple(jax.tree.leaves(bank["m"]) + jax.tree.leaves(bank["v"]))
+        for n in range(1, cap + 1):
+            # the boundary turns numpy slot lists into device indices
+            jnp.asarray(np.zeros((n,), np.int64), jnp.int32)
+            idx = jnp.zeros((n,), jnp.int32)
+            rows = gather(leaves, idx)
+            jax.block_until_ready(scatter(tuple(jnp.copy(x) for x in leaves),
+                                          idx, rows))
+
+
+def _warm_device_store(ma, group, n: int, bank: dict, store: dict) -> None:
+    """One boundary of ``n`` blocks of ``group`` each way through the
+    program's own moves, with every result dropped: the store writes are
+    functional on the device, and the commit donates a copy of the
+    bank."""
+    import jax
+    import jax.numpy as jnp
+    blocks = np.arange(n)
+    plan = ma.GroupSwapPlan(key=group.key, start=group.start,
+                            length=group.length, stacked=group.stacked,
+                            ev_blocks=blocks, ev_slots=blocks,
+                            ad_blocks=blocks, ad_slots=blocks)
+    rows = ma.gather_evictions([plan], {group.key: bank})
+    staged = ma.prefetch_admissions([plan], store)
+    jax.block_until_ready(rows)
+    new_store = ma.writeback_evictions([plan], rows, store)
+    del rows
+    if group.stacked:
+        bank = {"m": jax.tree.map(jnp.copy, bank["m"]),
+                "v": jax.tree.map(jnp.copy, bank["v"]),
+                "slots": bank["slots"]}
+    banks, _, _ = ma.commit_swap([plan], {group.key: bank}, store,
+                                 np.full((group.start + group.length,), -1,
+                                         np.int32), staged, donate=True)
+    jax.block_until_ready((banks, new_store))
 
 
 def _window_tokens(feed, cursor: int, steps: int) -> tuple[int, int]:
@@ -289,21 +327,11 @@ def _compare(prog: dict, ref: dict, limits: dict, cell) -> list:
 
 def reference_readings(cell, seed: int, batches: list, mode: str = "f32"):
     """The reference's first steps of the same job from the same seed."""
-    import jax.numpy as jnp
-
-    from reference import dense_decoder as ref
     job = cell.traffic["job"]
-    s = work.sizes(cell.config)
-    # the draw pads as the program does, so that both take its bits
-    mcfg = program.program_config(cell.config)
-    w0 = weights.canonical(seed, s, program.has_bias(cell.config),
-                           mcfg.padded_heads, mcfg.padded_vocab_size,
-                           jnp.dtype(cell.config["torch_dtype"]))
-    return ref.reference_train(
-        ref.consts(cell.config), w0, batches, nl=s["layers"],
-        opt=job["optimizer"], sel=job["select"], method=job["method"],
-        program_seed=program_seed(cell, seed), mode=mode,
-        param_dtype=jnp.dtype(cell.config["torch_dtype"]))
+    return cell.family.reference_train(
+        cell.config, seed, batches, opt=job["optimizer"], sel=job["select"],
+        method=job["method"], program_seed=program_seed(cell, seed),
+        mode=mode)
 
 
 def setup_steps(trainer, feed, cell, seed: int) -> dict:
@@ -329,18 +357,15 @@ def change_norms(prog: dict, cell, seed: int) -> None:
     """``prog["change"]``: per leaf, how far the checked steps moved the
     parameters from the seeded weights. Runs after ``release``, so that
     nothing of the check shares the device with the program's state."""
-    s = work.sizes(cell.config)
-    mcfg = program.program_config(cell.config)
-    params = prog.pop("params")
     prog["change"] = weights.change_norms(
-        params, seed, s, program.has_bias(cell.config),
-        mcfg.padded_heads, mcfg.padded_vocab_size)
+        prog.pop("params"), seed, cell.family.leaves(cell.config))
 
 
-def _say_memory(stage: str, devs) -> None:
+def _say_memory(stage: str, devs, t_start: float) -> None:
     stats = devs[0].memory_stats() or {}
-    _say(f"[memory] {stage}: in use {stats.get('bytes_in_use', 0)}, "
-         f"peak {stats.get('peak_bytes_in_use', 0)}")
+    _say(f"[memory] {stage} at {time.perf_counter() - t_start:.1f}s: in "
+         f"use {stats.get('bytes_in_use', 0)}, peak "
+         f"{stats.get('peak_bytes_in_use', 0)}")
 
 
 def release(trainer) -> None:
@@ -356,23 +381,21 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     import jax
     import jax.numpy as jnp
 
-    trainer, feed = build(cell, seed)
-    _say_memory("built", devs)
+    trainer, feed = build(cell, seed, devs)
+    _say_memory("built", devs, t_start)
     prog = setup_steps(trainer, feed, cell, seed)
-    _say_memory("after the first steps", devs)
+    _say_memory("after the first steps", devs, t_start)
     _warm_swap_shapes(trainer)
-    _say_memory("warmed", devs)
+    _say_memory("warmed", devs, t_start)
     steps = max(2, int(round(seconds / cell.workload["step_s"])))
     stats = getattr(trainer.step_fn, "swap_stats", None)
     swap0 = (stats.swap.total, stats.steps, stats.boundaries,
              stats.predicted_hits) if stats is not None else None
     counts0 = np.asarray(trainer.state["opt"]["counts"])
     cursor0 = feed.cursor()["record"]
-    compiles = harness.CompileCount()
     tracer = harness.Trace(cell, trace)
     tracer.start()
     _say(f"[window] start: {steps} steps")
-    compiles.on = True
     t0 = time.perf_counter()
     setup_s = t0 - t_start
     with jax.profiler.TraceAnnotation("bench_window"):
@@ -380,17 +403,18 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         jax.block_until_ready((trainer.state["params"],
                                trainer.state["opt"]))
     window_s = time.perf_counter() - t0
-    compiles.on = False
     tracer.stop()
-    _say(f"[window] {steps} steps in {window_s:.3f}s; programs compiled or "
-         f"loaded inside it: {compiles.requests}")
-    _say_memory("after the window", devs)
+    _say(f"[window] {steps} steps in {window_s}s")
+    _say_memory("after the window", devs, t_start)
     device = harness.device_report(devs)
     counts1 = np.asarray(trainer.state["opt"]["counts"])
     tokens, pairs = _window_tokens(feed, cursor0, steps)
-    s = work.sizes(cell.config)
+    # shares of a peak are of the cell's chips together: ``chips`` times
+    # one chip's peak over the window (a roofline reader's kernel time is
+    # per chip)
     ctx = {"kind": "train", "window_s": window_s, "steps": steps,
-           "tokens": tokens, "flops": work.train_flops(s, tokens, pairs),
+           "tokens": tokens, "chips": len(devs),
+           "flops": cell.family.train_flops(cell.config, tokens, pairs),
            "peaks": harness.peaks(device["kind"], cell.root)}
     if stats is not None:
         ctx["swap"] = {"swap_us": stats.swap.total - swap0[0],
@@ -400,9 +424,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         _say(f"[window] selection changed at {ctx['swap']['boundaries']} of "
              f"{ctx['swap']['steps']} steps; the swap planner had staged "
              f"{ctx['swap']['predicted_hits']} of them")
-    blocks = work.block_params(s, program.has_bias(cell.config))
-    per_block = np.array([blocks["embed"]] + [blocks["layer"]] * s["layers"]
-                         + [blocks["final_norm"]], np.int64)
+    per_block = np.array(cell.family.block_params(cell.config), np.int64)
     p_bytes = jnp.dtype(cell.config["torch_dtype"]).itemsize
     m_bytes = jnp.dtype(cell.traffic["job"]["optimizer"].get(
         "moment_dtype", "float32")).itemsize
@@ -423,9 +445,11 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     release(trainer)
     del trainer
     gc.collect()
+    t_ref = time.perf_counter()
     with contextlib.redirect_stdout(sys.stderr):
         change_norms(prog, cell, seed)
         ref = reference_readings(cell, seed, prog["batches"])
+    _say(f"[check] reference took {time.perf_counter() - t_ref:.1f}s")
     _say(f"[check] losses program {prog['losses']} reference {ref['losses']}")
     checks = _compare(prog, ref, cell.workload["limits"], cell)
     return harness.result_line(checks=checks, attempted=steps, failed=0,
